@@ -1,5 +1,4 @@
-"""Benchmark harness — one function per paper table/figure + the TPU
-roofline report.  Prints ``name,us_per_call,derived`` CSV rows
+"""Benchmark harness — one function per paper table/figure.  Prints ``name,us_per_call,derived`` CSV rows
 (us_per_call = wall time of the benchmark computation itself; derived =
 the headline metric that the corresponding paper artifact reports).
 
@@ -534,30 +533,6 @@ def bench_kernels():
 
 
 # ---------------------------------------------------------------------------
-# TPU roofline report (reads the dry-run artifacts).
-# ---------------------------------------------------------------------------
-
-def bench_roofline():
-    from benchmarks.roofline import pick_hillclimb_cells, summarize
-    t0 = time.perf_counter()
-    rows = summarize(print_table=False)
-    us = (time.perf_counter() - t0) * 1e6
-    if not rows:
-        emit("roofline_table", us, "no dry-run artifacts (run dryrun --all)")
-        return
-    emit("roofline_cells", us, f"n={len(rows)}")
-    picks = pick_hillclimb_cells(rows)
-    for why, r in picks.items():
-        emit(f"roofline_{why}", us,
-             f"{r['arch']}x{r['shape']} frac={r['frac']:.3f} "
-             f"dom={r['dominant']} coll_share={r['coll_share']:.2f}")
-    best = max((r for r in rows if r["mesh"] == "single"),
-               key=lambda r: r["frac"])
-    emit("roofline_best_cell", us,
-         f"{best['arch']}x{best['shape']} frac={best['frac']:.3f}")
-
-
-# ---------------------------------------------------------------------------
 # Tuned dispatch: the autotuner's measured end-to-end win.
 # ---------------------------------------------------------------------------
 
@@ -595,7 +570,6 @@ BENCHES = {
     "online": bench_online,
     "table7": bench_table7_area,
     "kernels": bench_kernels,
-    "roofline": bench_roofline,
     "tune": bench_tune,
 }
 

@@ -49,7 +49,7 @@ from repro.backend.base import (Backend, DispatchHandle, ExecResult,
 from repro.backend.registry import (ALIASES, available,
                                     default_matmul_backend, dispatch_platform,
                                     get, get_tuned, matmul_backend_string,
-                                    register, resolve,
+                                    register, resolve, routing_key,
                                     set_default_matmul_backend,
                                     set_dispatch_platform, set_tuned_dispatch,
                                     tuned_config, tuned_dispatch_enabled)
@@ -66,6 +66,7 @@ __all__ = [
     "NO_MATMUL_OPERANDS",
     "ALIASES", "available", "default_matmul_backend", "dispatch_platform",
     "get", "get_tuned", "matmul_backend_string", "register", "resolve",
+    "routing_key",
     "set_default_matmul_backend", "set_dispatch_platform",
     "set_tuned_dispatch", "tuned_config", "tuned_dispatch_enabled",
     "JaxBackend", "PallasBackend", "DESimBackend", "AnalyticalBackend",

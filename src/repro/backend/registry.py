@@ -153,6 +153,17 @@ def tuned_dispatch_enabled() -> bool:
     return _TUNED_DISPATCH
 
 
+def routing_key() -> tuple:
+    """Everything ``matmul_backend_string`` reads besides its arguments:
+    the default route, whether it was set explicitly, the dispatch
+    platform, the tuned-dispatch switch and the tuning caches' generation.
+    A jitted program whose trace resolves routes takes this as a static
+    argument, so changing any of them compiles a new program."""
+    from repro.tune.cache import generation
+    return (_DEFAULT_MATMUL, _MATMUL_SET_EXPLICITLY, _DISPATCH_PLATFORM,
+            _TUNED_DISPATCH, generation())
+
+
 def _platform_name(platform) -> str:
     from repro.core.hardware import PLATFORMS
     name = getattr(platform, "name", platform)

@@ -4,9 +4,11 @@
 ``desim-cluster`` backend times: ``sim.partition`` decides which unit
 owns which tiles, and execution maps units onto a ``(units,)`` mesh axis
 — ``distributed.sharding.shard_map_gemm`` computes each unit's output
-block under ``shard_map`` (``launch.mesh``) when enough devices exist,
-or through an arithmetically identical per-shard loop otherwise, so
-int8 results are bit-exact against the ``jax`` backend either way.
+block under ``shard_map`` (``launch.mesh``) with one device per unit.
+The modelled units are matrix units, not chips, so where the host has
+fewer devices than units this backend runs the units' spans as a loop
+on one device (``sliced_gemm``), and int8 results are bit-exact against the
+``jax`` backend either way.
 Epilogue-carrying vector nodes are applied to the assembled accumulator
 through the same region walk the single-device lowering uses
 (``sim.lower.apply_graph_epilogues``).
@@ -15,6 +17,8 @@ through the same region walk the single-device lowering uses
 from __future__ import annotations
 
 from typing import Callable
+
+import jax
 
 from repro.backend.base import (ExecResult, GraphOperands,
                                 MatMulOperands, NO_MATMUL_OPERANDS)
@@ -82,16 +86,16 @@ class ShardedBackend(PartitionedBackend):
         the partition's per-unit extent list, so execution reproduces
         the exact unit-to-data mapping the DES timed."""
         from repro.core.fusion import _infer_policy
-        from repro.distributed.sharding import shard_map_gemm
+        from repro.distributed.sharding import shard_map_gemm, sliced_gemm
         from repro.sim.lower import apply_graph_epilogues
         policy = _infer_policy(a)
         dim = self.shard_dim
         # layer-pipeline keeps each whole GEMM on one unit: within a
         # single GEMM there is nothing to shard.
         n = self.units if dim is not None else 1
-        acc = shard_map_gemm(a, b, n, dim=dim or "m",
-                             accum_dtype=policy.accum_dtype,
-                             precision=policy.dot_precision,
-                             bounds=spans if dim is not None else None)
+        gemm = shard_map_gemm if jax.device_count() >= n else sliced_gemm
+        acc = gemm(a, b, n, dim=dim or "m", accum_dtype=policy.accum_dtype,
+                   precision=policy.dot_precision,
+                   bounds=spans if dim is not None else None)
         return apply_graph_epilogues(graph, acc, operands=eops,
                                      in_dtype=a.dtype)

@@ -2,7 +2,7 @@
 
 ``input_specs(cfg, shape, mode)`` returns ShapeDtypeStruct stand-ins for
 every model input — weak-type-correct, shardable, no device allocation —
-consumed by the dry-run and the roofline benchmarks.
+consumed by compile-only rehearsals (``jax.jit(...).lower(...)``).
 """
 
 from __future__ import annotations

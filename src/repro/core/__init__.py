@@ -10,7 +10,6 @@ Public surface:
     API every model routes through.
   * ``simulator`` — cycle-approximate reproduction of the paper's
     evaluation platform.
-  * ``roofline`` — TPU three-term roofline for the dry-run analysis.
 """
 
 from repro.core.config import (CASE_STUDY, PLATFORM_2TOPS, MatrixUnitConfig,

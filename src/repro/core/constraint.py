@@ -117,8 +117,11 @@ class TileConfig:
 
 def tile_vmem_bytes(bm: int, bn: int, bk: int, in_bytes: float,
                     accum_bytes: int = 4, buffers: int = 2) -> int:
-    """VMEM working set: double-buffered A/B blocks + resident fp32 accum."""
-    return int(buffers * (bm * bk + bk * bn) * in_bytes + bm * bn * accum_bytes)
+    """VMEM working set: double-buffered A/B blocks, the resident fp32
+    accumulator, and the double-buffered output block (counted at
+    accumulator width, its widest case)."""
+    return int(buffers * (bm * bk + bk * bn) * in_bytes
+               + (1 + buffers) * bm * bn * accum_bytes)
 
 
 def tile_times(bm: int, bn: int, bk: int, dt: DataType,
@@ -136,11 +139,13 @@ def solve_tiles(dt: DataType = DataType.BF16, chip: TpuChip = TARGET_CHIP,
     """Pick (bm, bn, bk) under Eq. 2 logic with TPU constants.
 
     Grow the square output tile in MXU-aligned steps until compute per
-    tile covers DMA per tile, subject to the VMEM budget.  ``bk`` defaults
-    to a K-panel deep enough to amortise the MXU pipeline (≥ 128, several
-    lanes of the systolic array).
+    tile covers DMA per tile, subject to the VMEM budget: ``vmem_frac`` of
+    the chip's scoped VMEM, the share one kernel may use without raising
+    the compiler's limit.  ``bk`` defaults to a K-panel deep enough to
+    amortise the MXU pipeline (≥ 128, several lanes of the systolic
+    array).
     """
-    budget = chip.vmem_bytes * vmem_frac
+    budget = chip.scoped_vmem_bytes * vmem_frac
     pol = policy(dt)
     best = None
     t = lane

@@ -94,12 +94,23 @@ NO_OPERANDS = EpilogueOperands()
 
 
 def apply_epilogue(acc: jax.Array, ep: Epilogue, ops: EpilogueOperands,
-                   compute_dtype=jnp.float32) -> jax.Array:
+                   compute_dtype=jnp.float32,
+                   tile_operands: bool = False) -> jax.Array:
     """Pure-jnp epilogue application.  ``acc`` is (..., M, N) accumulator.
 
     Shared by the XLA backend, the Pallas kernel's reference oracle and —
-    on a per-tile basis — the Pallas kernel body itself.
+    on a per-tile basis — the Pallas kernel body itself.  The kernel
+    passes ``tile_operands=True``: its per-column operands arrive as
+    ``(1, N)`` and per-row ones as ``(M, 1)`` blocks (the TPU kernel
+    compiler lays 1-D blocks out differently from XLA), which already
+    broadcast against the tile.
     """
+    def col(x):          # per-column (N,) operand against (..., M, N)
+        return x if tile_operands else x[..., None, :]
+
+    def row(x):          # per-row (M,) operand against (..., M, N)
+        return x if tile_operands else x[..., :, None]
+
     out_dtype_final = ep.out_dtype if ep.out_dtype is not None else acc.dtype
     trivial = (not ep.has_scale_a and not ep.has_scale_b
                and ep.bias_type == BiasType.ZERO and not ep.softcap
@@ -110,11 +121,11 @@ def apply_epilogue(acc: jax.Array, ep: Epilogue, ops: EpilogueOperands,
         return acc.astype(out_dtype_final)
     y = acc.astype(compute_dtype)
     if ep.has_scale_a:
-        y = y * ops.scale_a[..., :, None].astype(compute_dtype)
+        y = y * row(ops.scale_a).astype(compute_dtype)
     if ep.has_scale_b:
-        y = y * ops.scale_b[..., None, :].astype(compute_dtype)
+        y = y * col(ops.scale_b).astype(compute_dtype)
     if ep.bias_type == BiasType.ROW:
-        y = y + ops.bias[..., None, :].astype(compute_dtype)
+        y = y + col(ops.bias).astype(compute_dtype)
     elif ep.bias_type == BiasType.FULL:
         y = y + ops.bias.astype(compute_dtype)
     if ep.softcap:
@@ -151,7 +162,7 @@ def cute_matmul(a: jax.Array, b: jax.Array, *,
                 operands: EpilogueOperands = NO_OPERANDS,
                 policy: Optional[PrecisionPolicy] = None,
                 backend: Optional[str] = None,
-                interpret: bool = True) -> jax.Array:
+                interpret: Optional[bool] = None) -> jax.Array:
     """C = epilogue(A @ B).  A: (..., M, K), B: (K, N) (or (..., K, N)).
 
     ``backend`` is a ``cute_matmul`` route string (``"xla"``,

@@ -9,8 +9,7 @@ Two families live here:
   paper's figures.
 
 * ``TpuChip`` — the TPU v5e target of the JAX/Pallas adaptation.  The
-  roofline analysis and the constraint model (``core.constraint``) read
-  their constants from here.
+  constraint model (``core.constraint``) reads its constants from here.
 
 All bandwidths are bytes/second, frequencies in Hz, throughputs in ops/s
 (1 MAC = 2 ops, matching the paper's Eq. 1).
@@ -127,7 +126,7 @@ BASELINES = {b.name: b for b in (XEON_8580, IBM_S1022, APPLE_M4)}
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class TpuChip:
-    """Per-chip constants for the roofline and the tile constraint model."""
+    """Per-chip constants for the tile and ICI constraint model."""
 
     name: str
     peak_bf16: float          # FLOP/s
@@ -136,7 +135,7 @@ class TpuChip:
     hbm_bytes: float          # capacity
     ici_bw: float             # bytes/s per link
     ici_links: int            # links per chip in a 2D torus
-    vmem_bytes: float         # software-managed vector memory
+    scoped_vmem_bytes: float  # vector memory one kernel may use by default
     mxu_shape: tuple = (128, 128)   # systolic array dims
     vpu_lanes: int = 8 * 128        # VPU ALUs
 
@@ -155,7 +154,9 @@ TPU_V5E = TpuChip(
     hbm_bytes=16 * GIBI,
     ici_bw=50 * GIGA,
     ici_links=4,
-    vmem_bytes=128 * MEBI,
+    # The TPU kernel compiler's default scoped-VMEM limit on v5e (of 128
+    # MiB physical): a kernel whose buffers exceed it is refused.
+    scoped_vmem_bytes=16 * MEBI,
 )
 
 TARGET_CHIP = TPU_V5E

@@ -19,7 +19,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro.core.jaxcompat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -29,7 +28,7 @@ def _ring_matmul(x_shard, w_shard, axis_name: str, n_dev: int):
     all-gather of W happens implicitly by rotating X? No: we rotate X
     shards around the ring and accumulate into the *full-M* output block
     owned by this device's W columns: out = all_gather(x) @ w_shard.
-    ``n_dev`` is passed statically (jax.lax.axis_size is newer jax)."""
+    ``n_dev`` is the ring size, passed statically."""
     idx = jax.lax.axis_index(axis_name)
     m_local = x_shard.shape[0]
     out = jnp.zeros((m_local * n_dev, w_shard.shape[1]), x_shard.dtype)
@@ -51,7 +50,7 @@ def _ring_matmul(x_shard, w_shard, axis_name: str, n_dev: int):
 def collective_matmul(x, w, mesh: Mesh, axis: str = "model"):
     """x: (M, K) sharded on M over ``axis``; w: (K, N) sharded on N.
     Returns (M, N) sharded on N (X implicitly all-gathered, overlapped)."""
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_matmul, axis_name=axis,
                           n_dev=mesh.shape[axis]), mesh=mesh,
         in_specs=(P(axis, None), P(None, axis)),

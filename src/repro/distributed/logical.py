@@ -8,7 +8,7 @@ size (gemma2-2b's 8 heads on a 16-way model axis silently fall back to
 GSPMD's choice — the divisibility-aware fallback of DESIGN.md §6).
 
 Changing the rules dict is the primary lever of the §Perf hillclimb:
-re-lower with a different mapping, re-read the roofline terms.
+re-lower with a different mapping.
 """
 
 from __future__ import annotations

@@ -19,7 +19,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro.core.jaxcompat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -45,7 +44,7 @@ def pipeline_apply(block_fn, stacked_params, x_microbatches, mesh: Mesh,
     perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()),               # params sharded by stage
         out_specs=P(), check_vma=False)
     def run(params_stage, xs):

@@ -123,15 +123,6 @@ def cache_shardings(cache, mesh: Optional[Mesh], cfg: ArchConfig,
         return jax.tree.map(one, cache)
 
 
-def apply_shardings(tree, shardings):
-    """Attach shardings to ShapeDtypeStructs (dry-run) or device_put (real)."""
-    def one(x, s):
-        if isinstance(x, jax.ShapeDtypeStruct):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s)
-        return x if s is None else jax.device_put(x, s)
-    return jax.tree.map(one, tree, shardings)
-
-
 # ---------------------------------------------------------------------------
 # Cluster-partitioned GEMM: the execution mirror of sim.partition.
 # ---------------------------------------------------------------------------
@@ -147,50 +138,46 @@ def shard_map_gemm(a, b, n_units: int, dim: str = "m",
     per-unit ``(lo, hi)`` extent list of a ``sim.partition.Partition``
     (``None`` entries for idle units), so execution reproduces the
     *exact* unit-to-data mapping the DES timed; omitted, an even split
-    is assumed.  When the spans are the even split and the host exposes
-    at least ``n_units`` devices the shards run under a real
-    ``shard_map`` over a ``(units,)`` mesh; otherwise an arithmetically
-    identical per-shard loop walks the spans (integer dots are
-    bit-exact either way, which is what the parity suite pins).
+    is assumed.  An even split runs under a real ``shard_map`` over a
+    ``(units,)`` mesh of ``n_units`` devices, and fewer devices is an
+    error; partition-shaped (unbalanced) spans run as :func:`sliced_gemm`
+    (integer dots are bit-exact either way, which is what the parity
+    suite pins).
 
     ``accum_dtype``/``precision`` mirror ``cute_matmul``'s dot so the
     shards accumulate exactly like the single-device kernel path.
     Returns the full (M, N) accumulator (int32 for int8 inputs).
     """
-    from repro.core.jaxcompat import shard_map
-
-    if dim not in ("m", "n"):
-        raise ValueError(f"dim must be 'm' or 'n', got {dim!r}")
-    if accum_dtype is None:
-        accum_dtype = jnp.int32 if a.dtype in (jnp.int8.dtype, jnp.uint8.dtype) \
-            else jnp.float32
-
-    def dot(a_s, b_s):
-        return jnp.matmul(a_s, b_s, preferred_element_type=accum_dtype,
-                          precision=precision)
-
     size = a.shape[0] if dim == "m" else b.shape[1]
-    even = [(size * u // n_units, size * (u + 1) // n_units)
-            for u in range(n_units)]
-    if bounds is None:
-        bounds = even
-    if (n_units == 1 or list(bounds) != even or size % n_units != 0
-            or jax.device_count() < n_units):
-        # Partition-shaped (possibly unbalanced) spans / too few
-        # devices: identical math, explicit per-span slices.
-        return _sliced_gemm(a, b, bounds, dim, dot)
+    if n_units == 1 or size % n_units or (
+            bounds is not None and list(bounds) != _even_spans(size, n_units)):
+        return sliced_gemm(a, b, n_units, dim, accum_dtype, precision, bounds)
+    if jax.device_count() < n_units:
+        raise ValueError(
+            f"shard_map_gemm over {n_units} units needs {n_units} devices, "
+            f"found {jax.device_count()}; sliced_gemm runs the same spans "
+            "as a loop on one device")
+    dot = _accumulating_dot(a, dim, accum_dtype, precision)
 
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((n_units,), (axis,))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((n_units,), (axis,))
     in_specs = (P(axis, None), P(None, None)) if dim == "m" \
         else (P(None, None), P(None, axis))
     out_specs = P(axis, None) if dim == "m" else P(None, axis)
-    fn = shard_map(dot, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(dot, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(a, b)
 
 
-def _sliced_gemm(a, b, bounds, dim, dot):
+def sliced_gemm(a, b, n_units: int, dim: str = "m", accum_dtype=None,
+                precision=None, bounds=None):
+    """``shard_map_gemm``'s result computed on one device: each unit's
+    span (``bounds``, else an even split) is one dot, and the blocks are
+    concatenated.  For modelled units that are not devices."""
+    dot = _accumulating_dot(a, dim, accum_dtype, precision)
+    if bounds is None:
+        bounds = _even_spans(a.shape[0] if dim == "m" else b.shape[1],
+                             n_units)
     parts = []
     for span in bounds:
         if span is None:
@@ -201,3 +188,21 @@ def _sliced_gemm(a, b, bounds, dim, dot):
         parts.append(dot(a[lo:hi], b) if dim == "m"
                      else dot(a, b[:, lo:hi]))
     return jnp.concatenate(parts, axis=0 if dim == "m" else 1)
+
+
+def _even_spans(size: int, n_units: int):
+    return [(size * u // n_units, size * (u + 1) // n_units)
+            for u in range(n_units)]
+
+
+def _accumulating_dot(a, dim, accum_dtype, precision):
+    if dim not in ("m", "n"):
+        raise ValueError(f"dim must be 'm' or 'n', got {dim!r}")
+    if accum_dtype is None:
+        accum_dtype = jnp.int32 if a.dtype in (jnp.int8.dtype, jnp.uint8.dtype) \
+            else jnp.float32
+
+    def dot(a_s, b_s):
+        return jnp.matmul(a_s, b_s, preferred_element_type=accum_dtype,
+                          precision=precision)
+    return dot

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels.attention.attention import (_STATS_LANES,
                                                flash_attention_kernel)
 
@@ -39,7 +40,7 @@ def flash_attention(q, k, v, *, sm_scale: Optional[float] = None,
                     causal: bool = True, window: int = 0,
                     softcap: float = 0.0, q_start: int = 0,
                     block_q: int = 128, block_kv: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D) -> (B, H, Sq, D)."""
     b, h, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
@@ -63,11 +64,8 @@ def flash_attention(q, k, v, *, sm_scale: Optional[float] = None,
         flash_attention_kernel, sm_scale=sm_scale, causal=causal,
         window=window, softcap=softcap, seq_len_k=sk, q_start=q_start,
         n_kv=grid[2], bq=bq, bkv=bkv)
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except (AttributeError, TypeError):
-        compiler_params = None
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     out = pl.pallas_call(
         kernel,
@@ -85,7 +83,7 @@ def flash_attention(q, k, v, *, sm_scale: Optional[float] = None,
             pltpu.VMEM((bq, d), jnp.float32),              # acc
         ],
         compiler_params=compiler_params,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, kp, vp)
     return out[:, :sq].reshape(b, h, sq, d)
 

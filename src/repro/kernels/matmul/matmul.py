@@ -65,18 +65,22 @@ def fused_matmul_kernel(*refs, ep: Epilogue, n_k: int, acc_dtype):
 
     @pl.when(k == n_k - 1)
     def _epilogue():
-        def _flat(ref):
-            # ROW bias / scale_b arrive as (2, bn/2) blocks under GLU
-            # (they ride the same (K, 2, N/2) column split as ``b``).
+        def _cols(ref):
+            # Per-column operands arrive as (1, bn) blocks, or under GLU
+            # as (2, bn/2) blocks riding the same column split as ``b``:
+            # gate half then up half, joined along lanes.
             if ref is None:
                 return None
             x = ref[...]
-            return x.reshape(-1) if (ep.glu and x.ndim == 2) else x
+            if ep.glu:
+                x = jnp.concatenate([x[0:1], x[1:2]], axis=1)
+            return x
 
         ops = EpilogueOperands(
-            bias=_flat(bias_ref),
+            bias=_cols(bias_ref),
             scale_a=None if scale_a_ref is None else scale_a_ref[...],
-            scale_b=_flat(scale_b_ref),
+            scale_b=_cols(scale_b_ref),
             residual=None if residual_ref is None else residual_ref[...],
         )
-        o_ref[...] = apply_epilogue(acc_ref[...], ep, ops)
+        o_ref[...] = apply_epilogue(acc_ref[...], ep, ops,
+                                    tile_operands=True)

@@ -20,6 +20,7 @@ from repro.core import constraint
 from repro.core.fusion import Epilogue, EpilogueOperands
 from repro.core.precision import PrecisionPolicy
 from repro.core.task import BiasType
+from repro.kernels import resolve_interpret
 from repro.kernels.matmul.matmul import fused_matmul_kernel
 
 _LANE = 128
@@ -63,7 +64,7 @@ def fused_matmul(a: jax.Array, b: jax.Array, *,
                  operands: EpilogueOperands = EpilogueOperands(),
                  policy: Optional[PrecisionPolicy] = None,
                  block_shape: Optional[tuple] = None,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """epilogue(a @ b).  a: (..., M, K); b: (K, N) or (K, 2, N/2) for GLU."""
     from repro.core.fusion import _infer_policy   # cycle-free at call time
     if policy is None:
@@ -82,6 +83,9 @@ def fused_matmul(a: jax.Array, b: jax.Array, *,
     n_logical = b.shape[-1] * (2 if b.ndim == 3 else 1)
 
     bm, bn, bk = block_shape or default_tiles(m, n_logical, k, policy)
+    if epilogue.glu and block_shape is None:
+        # each GLU half of the column block is a whole number of lanes
+        bn = 2 * _round_up(bn // 2, _LANE)
     a2 = _pad_to(_pad_to(a2, 0, bm), 1, bk)
     if b.ndim == 3:
         b_p = _pad_to(_pad_to(b, 0, bk), 2, bn // 2)
@@ -104,14 +108,12 @@ def fused_matmul(a: jax.Array, b: jax.Array, *,
     ]
 
     def _add_col_operand(x, width):
-        """(N,)-shaped epilogue operand, padded & blocked along columns."""
-        if epilogue.glu:
-            x = _pad_to(x.reshape(2, -1), 1, width // 2)
-            in_specs.append(pl.BlockSpec((2, width // 2),
-                                         lambda i, j, kk: (0, j)))
-        else:
-            x = _pad_to(x, 0, width)
-            in_specs.append(pl.BlockSpec((width,), lambda i, j, kk: (j,)))
+        """(N,)-shaped epilogue operand as a 2-D row, padded & blocked
+        along columns ((2, N/2) under GLU, like ``b``)."""
+        rows = 2 if epilogue.glu else 1
+        x = _pad_to(x.reshape(rows, -1), 1, width // rows)
+        in_specs.append(pl.BlockSpec((rows, width // rows),
+                                     lambda i, j, kk: (0, j)))
         in_arrays.append(x)
 
     if epilogue.bias_type == BiasType.ROW:
@@ -120,8 +122,8 @@ def fused_matmul(a: jax.Array, b: jax.Array, *,
         in_arrays.append(_pad_to(_pad_to(operands.bias, 0, bm), 1, bn))
         in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)))
     if epilogue.has_scale_a:
-        in_arrays.append(_pad_to(operands.scale_a.reshape(-1), 0, bm))
-        in_specs.append(pl.BlockSpec((bm,), lambda i, j, kk: (i,)))
+        in_arrays.append(_pad_to(operands.scale_a.reshape(-1, 1), 0, bm))
+        in_specs.append(pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)))
     if epilogue.has_scale_b:
         _add_col_operand(operands.scale_b, bn)
     if epilogue.has_residual:
@@ -131,11 +133,8 @@ def fused_matmul(a: jax.Array, b: jax.Array, *,
 
     kernel = functools.partial(fused_matmul_kernel, ep=epilogue,
                                n_k=grid[2], acc_dtype=acc_dtype)
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except (AttributeError, TypeError):
-        compiler_params = None
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     out = pl.pallas_call(
         kernel,
@@ -145,7 +144,7 @@ def fused_matmul(a: jax.Array, b: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((mp, n_out), epilogue.out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         compiler_params=compiler_params,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*in_arrays)
 
     out = out[:m, : (n_logical // 2 if epilogue.glu else n_logical)]

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +12,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fusion import Epilogue
+from repro.kernels import resolve_interpret
 from repro.kernels.moe.grouped_matmul import grouped_matmul_kernel
 
 
@@ -26,7 +28,8 @@ def _pad(x, axis, mult):
 @functools.partial(jax.jit, static_argnames=("epilogue", "block_shape",
                                              "interpret"))
 def grouped_matmul(x, w, *, epilogue: Epilogue = Epilogue(),
-                   block_shape=(128, 128, 128), interpret: bool = True):
+                   block_shape=(128, 128, 128),
+                   interpret: Optional[bool] = None):
     """x: (E, C, K); w: (E, K, N) (or (E, K, 2, N/2) for GLU) -> (E, C, N')."""
     e, cap, k = x.shape
     if epilogue.glu and w.ndim == 3:
@@ -39,7 +42,11 @@ def grouped_matmul(x, w, *, epilogue: Epilogue = Epilogue(),
 
     bm, bn, bk = block_shape
     bm = min(bm, _round_up(cap, 8))
-    bn = min(bn, _round_up(n_logical, 128))
+    if w.ndim == 4:
+        # each GLU half of the column block is a whole number of lanes
+        bn = 2 * min(_round_up(bn // 2, 128), _round_up(n_logical // 2, 128))
+    else:
+        bn = min(bn, _round_up(n_logical, 128))
     bk = min(bk, _round_up(k, 128))
     x = _pad(_pad(x, 1, bm), 2, bk)
     if w.ndim == 4:
@@ -59,12 +66,9 @@ def grouped_matmul(x, w, *, epilogue: Epilogue = Epilogue(),
 
     kernel = functools.partial(grouped_matmul_kernel, ep=epilogue,
                                n_k=grid[3])
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except (AttributeError, TypeError):
-        compiler_params = None
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
 
     out = pl.pallas_call(
         kernel,
@@ -78,7 +82,7 @@ def grouped_matmul(x, w, *, epilogue: Epilogue = Epilogue(),
         out_shape=jax.ShapeDtypeStruct((e, cp, n_out), epilogue.out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         compiler_params=compiler_params,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)
     return out[:, :cap, : (n_logical // 2 if epilogue.glu else n_logical)]
 

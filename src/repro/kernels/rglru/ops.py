@@ -3,18 +3,20 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels.rglru.rglru import rglru_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_c", "interpret"))
 def rglru_scan(log_a, x, *, chunk: int = 64, block_c: int = 512,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     """log_a, x: (B, T, C) -> h sequence (B, T, C), zero initial state."""
     b, t, c = x.shape
     pad_t = (-t) % chunk
@@ -32,11 +34,8 @@ def rglru_scan(log_a, x, *, chunk: int = 64, block_c: int = 512,
     grid = (b, cp // bc, tp // chunk)     # time innermost (sequential)
 
     kernel = functools.partial(rglru_kernel, chunk=chunk)
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except (AttributeError, TypeError):
-        compiler_params = None
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     o = pl.pallas_call(
         kernel,
@@ -45,10 +44,12 @@ def rglru_scan(log_a, x, *, chunk: int = 64, block_c: int = 512,
             pl.BlockSpec((1, chunk, bc), lambda bi, ci, ti: (bi, ti, ci)),
             pl.BlockSpec((1, chunk, bc), lambda bi, ci, ti: (bi, ti, ci)),
         ],
-        out_specs=pl.BlockSpec((1, chunk, bc), lambda bi, ci, ti: (bi, ti, ci)),
+        out_specs=pl.BlockSpec((1, chunk, bc),
+                               lambda bi, ci, ti: (bi, ti, ci)),
         out_shape=jax.ShapeDtypeStruct((b, tp, cp), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1, bc), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, bc), jnp.float32)]
+        + [pltpu.VMEM((chunk, bc), jnp.float32)] * 3,
         compiler_params=compiler_params,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(log_a, x)
     return o[:, :t, :c]

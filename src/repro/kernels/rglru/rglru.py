@@ -20,7 +20,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def rglru_kernel(log_a_ref, x_ref, o_ref, h_ref, *, chunk: int):
+def one_minus_exp(y):
+    """``1 - exp(y)`` for ``y <= 0`` without cancellation near 0 (what
+    ``-expm1(y)`` computes; the TPU kernel compiler has no ``expm1``).
+    Below |y| = 0.1 a 5-term Taylor series is exact to float32."""
+    series = -y * (1.0 + y * (0.5 + y * (1.0 / 6.0 + y * (
+        1.0 / 24.0 + y * (1.0 / 120.0)))))
+    return jnp.where(y > -0.1, series, 1.0 - jnp.exp(y))
+
+
+def rglru_kernel(log_a_ref, x_ref, o_ref, h_ref, a_ref, gx_ref, out_ref, *,
+                 chunk: int):
+    """Scratch: ``h_ref`` (1, bc) carry; ``a_ref``/``gx_ref``/``out_ref``
+    (L, bc) float32 staging, so the time loop indexes 32-bit refs one row
+    at a time (a dynamic row of a value, or of a packed bf16 block, is
+    not something the TPU kernel compiler lowers)."""
     t0 = pl.program_id(2)
 
     @pl.when(t0 == 0)
@@ -28,17 +42,15 @@ def rglru_kernel(log_a_ref, x_ref, o_ref, h_ref, *, chunk: int):
         h_ref[...] = jnp.zeros_like(h_ref)
 
     log_a = log_a_ref[0].astype(jnp.float32)      # (L, bc)
-    x = x_ref[0].astype(jnp.float32)              # (L, bc)
-    a = jnp.exp(log_a)
-    # sqrt(1 - a^2) computed stably: 1 - exp(2·log_a) via expm1.
-    beta = jnp.sqrt(-jnp.expm1(2.0 * log_a))
-    gated = beta * x
+    a_ref[...] = jnp.exp(log_a)
+    gx_ref[...] = jnp.sqrt(one_minus_exp(2.0 * log_a)) * x_ref[0].astype(
+        jnp.float32)
 
     def body(t, h):
-        h = a[t] * h + gated[t]
-        pl.store(o_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 h[None, None].astype(o_ref.dtype))
+        row = pl.ds(t, 1)
+        h = a_ref[row, :] * h + gx_ref[row, :]    # (1, bc)
+        out_ref[row, :] = h
         return h
 
-    h_final = jax.lax.fori_loop(0, chunk, body, h_ref[0, :])
-    h_ref[0, :] = h_final
+    h_ref[...] = jax.lax.fori_loop(0, chunk, body, h_ref[...])
+    o_ref[0] = out_ref[...].astype(o_ref.dtype)

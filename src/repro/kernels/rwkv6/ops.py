@@ -3,17 +3,20 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels.rwkv6.rwkv6 import rwkv6_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_scan(r, k, v, lw, u, *, chunk: int = 32, interpret: bool = True):
+def rwkv6_scan(r, k, v, lw, u, *, chunk: int = 32,
+               interpret: Optional[bool] = None):
     """r/k/v/lw: (B, H, T, C); u: (H, C) -> o (B, H, T, C).
 
     T must be a multiple of ``chunk`` (the wrapper pads with zero decay /
@@ -31,11 +34,8 @@ def rwkv6_scan(r, k, v, lw, u, *, chunk: int = 32, interpret: bool = True):
     grid = (b * h, tp // chunk)
 
     kernel = functools.partial(rwkv6_kernel, n_chunks=grid[1])
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    except (AttributeError, TypeError):
-        compiler_params = None
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
     o = pl.pallas_call(
         kernel,
@@ -45,12 +45,13 @@ def rwkv6_scan(r, k, v, lw, u, *, chunk: int = 32, interpret: bool = True):
             pl.BlockSpec((1, chunk, c), lambda bh, ch: (bh, ch, 0)),
             pl.BlockSpec((1, chunk, c), lambda bh, ch: (bh, ch, 0)),
             pl.BlockSpec((1, chunk, c), lambda bh, ch: (bh, ch, 0)),
-            pl.BlockSpec((1, c), lambda bh, ch: (bh % h, 0)),
+            pl.BlockSpec((1, 1, c), lambda bh, ch: (bh % h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, c), lambda bh, ch: (bh, ch, 0)),
         out_shape=jax.ShapeDtypeStruct(shp, r.dtype),
-        scratch_shapes=[pltpu.VMEM((c, c), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((c, c), jnp.float32)]
+        + [pltpu.VMEM((chunk, c), jnp.float32)] * 3,
         compiler_params=compiler_params,
-        interpret=interpret,
-    )(r2, k2, v2, lw2, u)
+        interpret=resolve_interpret(interpret),
+    )(r2, k2, v2, lw2, u.reshape(h, 1, c))
     return o.reshape(b, h, tp, c)[:, :, :t]

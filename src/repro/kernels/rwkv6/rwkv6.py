@@ -15,9 +15,9 @@ matrix/vector split applied inside a single operator.
 Numerics: everything is kept in log space with non-positive exponents —
 ``exp(la_{t-1} - la_s)`` for s < t and ``exp(la_L - la_s)`` are both ≤ 1
 because cumulative log-decay is non-increasing.  The intra-chunk term is
-computed with an explicit (L, L, C) pairwise tensor, which is exact and
-overflow-free (a production kernel would use the GLA two-level split;
-with L = chunk 32–64 and C = 64 the tensor is ≤ 1 MiB of VMEM).
+computed one query row at a time over an (L, C) pairwise block, which
+is exact and overflow-free (a production kernel would use the GLA
+two-level split).
 
 Grid: (B·H, T/L) — chunk axis sequential, state carried in VMEM scratch.
 """
@@ -29,8 +29,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def rwkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
-                 n_chunks: int):
+def rwkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref,
+                 r_st, lap_st, intra_st, *, n_chunks: int):
+    """Scratch: ``s_ref`` (C, C) state carry; ``r_st``/``lap_st``/
+    ``intra_st`` (L, C) float32 staging for the per-step rows of the
+    intra-chunk term."""
     c = pl.program_id(1)
 
     @pl.when(c == 0)
@@ -41,31 +44,56 @@ def rwkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     lw = lw_ref[0].astype(jnp.float32)    # (L, C), log decay <= 0
-    u = u_ref[0].astype(jnp.float32)      # (C,)
+    u = u_ref[0].astype(jnp.float32)      # (1, C)
     L = r.shape[0]
 
-    la = jnp.cumsum(lw, axis=0)           # inclusive prefix log-decay
+    # Inclusive prefix log-decay, as a lower-triangular matmul (the TPU
+    # kernel compiler has no cumsum; HIGHEST keeps the f32 sum exact).
+    tril = (jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+            >= jax.lax.broadcasted_iota(jnp.int32, (L, L), 1))
+    la = jnp.dot(tril.astype(jnp.float32), lw,
+                 precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)
     la_prev = la - lw                     # la_{t-1} (la_0 = 0)
 
     # Inter-chunk: r_t ⊙ exp(la_{t-1}) @ S_0          (MXU)
     q_t = r * jnp.exp(la_prev)
     o = jnp.dot(q_t, s_ref[...], preferred_element_type=jnp.float32)
 
-    # Intra-chunk: P[t,s] = Σ_c r_tc k_sc exp(la_{t-1,c} - la_{s,c}), s<t.
-    diff = la_prev[:, None, :] - la[None, :, :]        # (L, L, C), <=0 for s<t
-    mask = (jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-            > jax.lax.broadcasted_iota(jnp.int32, (L, L), 1))
-    pair = r[:, None, :] * k[None, :, :] * jnp.exp(
-        jnp.where(mask[..., None], diff, -1e30))
-    p = jnp.sum(pair, axis=-1)                         # (L, L)
-    o += jnp.dot(p, v, preferred_element_type=jnp.float32)
+    # Intra-chunk, one query row t at a time (VPU):
+    #   o_t += Σ_{s<t} (Σ_c r_tc k_sc exp(la_{t-1,c} - la_{s,c})) v_s
+    # Every exponent is <= 0 for s < t, so the sum is overflow-free.
+    r_st[...] = r
+    lap_st[...] = la_prev
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
+
+    def intra_row(t, carry):
+        row = pl.ds(t, 1)
+        e = jnp.where(s_idx < t, lap_st[row, :] - la, -1e30)     # (L, C)
+        w = jnp.sum(r_st[row, :] * k * jnp.exp(e), axis=1,
+                    keepdims=True)                              # (L, 1)
+        intra_st[row, :] = jnp.sum(w * v, axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, L, intra_row, 0)
+    o += intra_st[...]
 
     # Bonus diagonal: ((r_t ⊙ u) · k_t) v_t            (VPU)
-    o += jnp.sum(r * u[None, :] * k, axis=-1, keepdims=True) * v
+    o += jnp.sum(r * u * k, axis=-1, keepdims=True) * v
     o_ref[0] = o.astype(o_ref.dtype)
 
     # State update: S_L = diag(exp(la_L)) S_0 + (K ⊙ exp(la_L - la_s))ᵀ V.
-    la_last = la[-1]                                   # (C,)
-    k_scaled = k * jnp.exp(la_last[None, :] - la)      # <= 1 factors
-    s_ref[...] = (jnp.exp(la_last)[:, None] * s_ref[...]
-                  + jnp.dot(k_scaled.T, v, preferred_element_type=jnp.float32))
+    la_last = la[L - 1:L]                              # (1, C)
+    k_scaled = k * jnp.exp(la_last - la)               # <= 1 factors
+    # diag(exp(la_L)) as a (C, C) matrix: the row scaling stays a matmul
+    # instead of a lane-to-sublane relayout of the (1, C) decay row.
+    n = s_ref.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    decay = jnp.where(eye, jnp.exp(la_last), 0.0)
+    s_ref[...] = (jnp.dot(decay, s_ref[...],
+                          precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
+                  + jax.lax.dot_general(
+                      k_scaled, v, (((0,), (0,)), ((), ())),
+                      preferred_element_type=jnp.float32))

@@ -1,58 +1,30 @@
-"""Production mesh builders + jax-version compatibility shims.
+"""Mesh builders over ``jax.make_mesh``.
 
 Functions, not module-level constants: importing this module never
-touches jax device state.  Single pod: 16×16 = 256 chips (data, model);
-multi-pod: 2×16×16 = 512 chips with an explicit "pod" axis that the
-default sharding rules fold into data parallelism (DESIGN.md §3).
-
-``compat_make_mesh`` / ``compat_abstract_mesh`` paper over the
-``AxisType`` / ``AbstractMesh`` API churn between jax 0.4.x and newer
-releases so the same code (and tests) run on both.
+touches jax device state.  Every mesh has ``AxisType.Auto`` axes: the
+model zoo places arrays through the logical sharding rules
+(``distributed.logical``) and lets GSPMD propagate the rest.  Single
+pod: 16×16 = 256 chips (data, model); multi-pod: 2×16×16 = 512 chips
+with an explicit "pod" axis that the default sharding rules fold into
+data parallelism (DESIGN.md §3).
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # newer jax
-    from jax.sharding import AxisType
-except ImportError:  # jax <= 0.4.x has no explicit/auto axis types
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across versions (``axis_types`` kwarg is newer
-    jax; ``jax.make_mesh`` itself is absent before 0.4.35)."""
-    shape, axes = tuple(shape), tuple(axes)
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-    from jax.sharding import Mesh
-    return Mesh(mesh_utils.create_device_mesh(shape), axes)
-
-
-def compat_abstract_mesh(sizes, names):
-    """``AbstractMesh`` across the (sizes, names) vs shape_tuple signatures."""
-    from jax.sharding import AbstractMesh
-    params = list(inspect.signature(AbstractMesh.__init__).parameters)
-    if "shape_tuple" in params:  # jax 0.4.x: tuple of (name, size) pairs
-        return AbstractMesh(tuple(zip(names, sizes)))
-    return AbstractMesh(tuple(sizes), tuple(names))
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes, over ``devices`` if given."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
-
-def make_host_mesh(model: int = 1):
-    """Debug mesh over whatever devices exist (tests / examples)."""
-    n = jax.device_count()
-    model = min(model, n)
-    return compat_make_mesh((n // model, model), ("data", "model"))

@@ -1,7 +1,11 @@
 """Serving launcher: batched generation over the async engine.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --reduced \\
-        --requests 6 --max-new 16
+    PYTHONPATH=src python -m repro.launch.serve --arch yi-6b \\
+        --requests 8 --prompt-len 512 --max-new 32
+
+serves the model at its published widths with random weights drawn from
+``--seed`` (on one TPU v5e for yi-6b); ``--reduced`` is the CPU-sized
+smoke configuration in float32.  Prompts in a batch have equal lengths.
 
 ``--plan BACKEND`` prices the queued batch schedule on a modelling
 backend from the ``repro.backend`` registry before serving: the queue is
@@ -25,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import ALL_ARCHS, get_config
-from repro.models.base import family_module
+from repro.models.base import init_params
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.engine import ServingEngine
 
 
@@ -155,6 +160,15 @@ def main(argv=None):
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument("--prompt-len", type=int, default=None,
+                    help="tokens per prompt (every prompt has this length; "
+                         "default 512, or what fits the model's decoder "
+                         "positions with --max-new)")
+    ap.add_argument("--cache-len", type=int, default=None,
+                    help="KV-cache slots per sequence (default: prompt "
+                         "length + --max-new)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--plan", default=None, metavar="BACKEND",
@@ -215,6 +229,7 @@ def main(argv=None):
                          "and write its snapshot to PATH on exit (JSON, "
                          "or Prometheus text when PATH ends in .prom)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     reg = None
     if args.metrics_out:
@@ -229,17 +244,25 @@ def main(argv=None):
     if args.qps is not None or args.arrival_trace is not None:
         _run_online(args, cfg, reg)
         return
-    mod = family_module(cfg)
-    params = mod.init(cfg, jax.random.PRNGKey(0))
+    # Learned decoder positions (encdec) bound prompt plus generation.
+    limit = cfg.encdec.max_positions if cfg.encdec is not None else None
+    prompt_len = args.prompt_len
+    if prompt_len is None:
+        prompt_len = 512 if limit is None else min(512, limit - args.max_new)
+    if limit is not None and prompt_len + args.max_new > limit:
+        ap.error(f"--prompt-len {prompt_len} plus --max-new {args.max_new} "
+                 f"exceeds the {limit} decoder positions of {cfg.name}")
+    key, prompt_key = jax.random.split(jax.random.PRNGKey(args.seed))
+    params = init_params(cfg, key)
 
     eng = ServingEngine(cfg, params, max_batch=args.max_batch,
-                        cache_len=256)
-    key = jax.random.PRNGKey(1)
+                        cache_len=(args.cache_len
+                                   or prompt_len + args.max_new))
+    prompts = jax.random.randint(prompt_key,
+                                 (args.requests, prompt_len), 0,
+                                 cfg.vocab_size)
     for i in range(args.requests):
-        n = 4 + (i * 3) % 12
-        key, sub = jax.random.split(key)
-        eng.submit(jax.random.randint(sub, (n,), 0, cfg.vocab_size),
-                   arrival_time=i * args.arrival_gap)
+        eng.submit(prompts[i], arrival_time=i * args.arrival_gap)
     if args.plan:
         from repro.serving.scheduler import (decode_latency_stats,
                                              price_steps)

@@ -5,13 +5,18 @@
 
 Production shape: config → mesh → sharded state → fault-tolerant loop
 (async checkpoints, straggler watchdog, preemption handler, auto-resume).
-On this CPU host the mesh is whatever ``jax.device_count()`` provides;
-on a real cluster the same flags drive the 16×16 / 2×16×16 meshes.
+``--mesh host`` spans the first ``--devices`` devices (all by default)
+as a (data, model) mesh with ``--model-parallel`` on the model axis; on
+a real cluster ``single``/``multi`` drive the 16×16 / 2×16×16 meshes.
+``--layers`` cuts the model's depth, keeping its widths.  The state is
+created already sharded (one jitted program each for the parameters and
+the optimizer), so no device ever holds the whole of it.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
@@ -20,10 +25,11 @@ import jax.numpy as jnp
 from repro.configs.registry import ALL_ARCHS, get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed import logical, sharding
-from repro.launch.mesh import make_host_mesh, make_production_mesh
-from repro.models.base import family_module
+from repro.launch.mesh import make_mesh, make_production_mesh
+from repro.models.base import family_module, init_params
 from repro.optim import adamw
 from repro.runtime.checkpoint import CheckpointManager
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.watchdog import PreemptionHandler, StepWatchdog
 from repro.training.train_step import TrainConfig, make_train_step
 
@@ -44,19 +50,56 @@ def parse_args(argv=None):
     ap.add_argument("--mesh", choices=("host", "single", "multi"),
                     default="host")
     ap.add_argument("--model-parallel", type=int, default=1)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="--mesh host over the first N devices (all)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model to N layers (published widths)")
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     return ap.parse_args(argv)
 
 
+def init_state(cfg, tcfg: TrainConfig, mesh, key):
+    """(params, opt_state), each created by one jitted program directly
+    in the shardings the name rules give it on ``mesh``."""
+    mod = family_module(cfg)
+    abstract = jax.eval_shape(functools.partial(mod.init, cfg), key)
+    pshard = sharding.param_shardings(abstract, mesh)
+    params = init_params(cfg, key, out_shardings=pshard)
+    opt_init = functools.partial(adamw.init, tcfg.optimizer)
+    oshard = sharding.param_shardings(jax.eval_shape(opt_init, params),
+                                      mesh)
+    return params, jax.jit(opt_init, out_shardings=oshard)(params)
+
+
+def device_memory() -> "list[dict]":
+    """Per-device bytes in use and peak, where the backend reports them."""
+    out = []
+    for d in jax.devices():
+        st = d.memory_stats() or {}
+        out.append({"device": d.id,
+                    "bytes_in_use": st.get("bytes_in_use"),
+                    "peak_bytes_in_use": st.get("peak_bytes_in_use")})
+    return out
+
+
 def main(argv=None):
+    """Train; returns ``{"loss": [...], "grad_norm": [...], "memory":
+    device_memory()}`` with one entry per step run (memory is read while
+    the state is still alive)."""
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     if args.reduced:
         cfg = cfg.with_(dtype=jnp.float32, remat="none")
-    mod = family_module(cfg)
+    if args.layers is not None:
+        cfg = cfg.with_(n_layers=args.layers)
 
     if args.mesh == "host":
-        mesh = make_host_mesh(model=args.model_parallel)
+        devices = jax.devices()[:args.devices]
+        model = min(args.model_parallel, len(devices))
+        mesh = make_mesh((len(devices) // model, model), ("data", "model"),
+                         devices=devices)
     else:
         mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
 
@@ -70,17 +113,16 @@ def main(argv=None):
 
     data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size,
                                   global_batch=args.global_batch,
-                                  seq_len=args.seq_len))
+                                  seq_len=args.seq_len, seed=args.seed))
 
     mgr = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
     watchdog = StepWatchdog()
     preempt = PreemptionHandler()
+    history = {"loss": [], "grad_norm": []}
 
     with logical.use_rules(mesh, None):
-        params = mod.init(cfg, jax.random.PRNGKey(0))
-        pshard = sharding.param_shardings(params, mesh)
-        params = sharding.apply_shardings(params, pshard)
-        opt = adamw.init(tcfg.optimizer, params)
+        params, opt = init_state(cfg, tcfg, mesh,
+                                 jax.random.PRNGKey(args.seed))
         residual = None
         start = 0
         if mgr and mgr.latest_step() is not None:
@@ -97,13 +139,15 @@ def main(argv=None):
             batch = next(data)
             params, opt, metrics, residual = jit_step(params, opt, batch,
                                                       residual)
-            jax.block_until_ready(metrics["loss"])
+            loss = float(metrics["loss"])
+            gnorm = float(metrics["grad_norm"])
             dt = time.perf_counter() - t0
+            history["loss"].append(loss)
+            history["grad_norm"].append(gnorm)
             slow = watchdog.record_step(dt)
             if step % args.log_every == 0 or slow:
                 tag = " STRAGGLER" if slow else ""
-                print(f"step {step:5d} loss {float(metrics['loss']):.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.3f} "
+                print(f"step {step:5d} loss {loss:.4f} gnorm {gnorm:.3f} "
                       f"{dt * 1e3:.0f}ms{tag}", flush=True)
             want_ckpt = mgr and ((step + 1) % args.ckpt_every == 0
                                  or preempt.requested)
@@ -116,10 +160,11 @@ def main(argv=None):
                 break
         if mgr:
             mgr.wait()
+        history["memory"] = device_memory()
     watchdog.close()
     print(f"done: {watchdog.steps} steps, "
           f"{watchdog.straggler_events} straggler events")
-    return params
+    return history
 
 
 if __name__ == "__main__":

@@ -184,3 +184,13 @@ def family_module(cfg: ArchConfig):
     # Import for side effects (registration); idempotent via sys.modules.
     from repro.models import transformer, rwkv6, recurrentgemma, whisper  # noqa: F401
     return _REGISTRY[cfg.family]
+
+
+def init_params(cfg: ArchConfig, key, out_shardings=None):
+    """``init`` of ``cfg``'s family as one jitted program: the weights
+    are drawn on the device, placed by ``out_shardings`` when given, and
+    peak device memory stays near the size of the model."""
+    import jax
+    mod = family_module(cfg)
+    return jax.jit(mod.init, static_argnums=0,
+                   out_shardings=out_shardings)(cfg, key)

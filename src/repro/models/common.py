@@ -29,13 +29,17 @@ from repro.models.base import ArchConfig
 # Initializers.
 # ---------------------------------------------------------------------------
 
+# Both draw directly in the parameter dtype: a float32 draw cast to bf16
+# would briefly hold twice the model in device memory.
+
 def dense_init(key, shape, dtype, in_axis: int = 0):
     fan_in = shape[in_axis]
-    return (jax.random.normal(key, shape) / jnp.sqrt(fan_in)).astype(dtype)
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(
+        fan_in ** -0.5, dtype)
 
 
 def embed_init(key, shape, dtype):
-    return (jax.random.normal(key, shape) * 0.02).astype(dtype)
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(0.02, dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from repro.core.jaxcompat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.fusion import Epilogue, linear
@@ -142,7 +141,7 @@ def moe_apply(cfg: ArchConfig, p, x, mesh: Optional[Mesh] = None):
         capacity = moe_capacity(cfg, t_local)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(data_axes, None, None), P(), P("model", None, None),
                       P("model", None, None)),
             out_specs=P(data_axes, None, None),

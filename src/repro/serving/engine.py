@@ -13,30 +13,37 @@ The paper's asyncMatMul/checkMatmul contract shows up twice here:
   timelines (and the identical schedule graph executed bit-exactly by
   ``backend.get("jax")``) before it ever hits hardware.
 
-``generate`` is the synchronous core: prefill the prompt batch, then a
-``lax.scan`` decode loop with greedy/temperature sampling.
+``generate`` is the synchronous core, compiled as one program: prefill
+the prompt batch, then a ``lax.scan`` decode loop with greedy/temperature
+sampling.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import zlib
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.backend.registry import routing_key
 from repro.core.precision import DataType
 from repro.core.simulator import VECTOR_OP_INSTRS, LayerTrace
 from repro.core.task import MatMulTask
 from repro.models.base import ArchConfig, family_module
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class GenerateResult:
     tokens: jax.Array          # (B, n_new)
-    logits_last: jax.Array     # (B, V)
-    steps: int
+    logits_last: jax.Array     # (B, V): the logits the last token came from
+    steps: int = dataclasses.field(metadata=dict(static=True))
+    # (B, n_new, V): the logits every token came from; only with
+    # ``generate(keep_logits=True)``, so serving never holds the stack.
+    logits: Optional[jax.Array] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,8 +89,35 @@ def sample(logits, key, temperature: float = 0.0):
 
 def generate(cfg: ArchConfig, params, batch, *, max_new_tokens: int,
              temperature: float = 0.0, key=None,
-             cache_len: Optional[int] = None) -> GenerateResult:
-    """Prefill + scan-decode.  batch["tokens"]: (B, S_prompt)."""
+             cache_len: Optional[int] = None,
+             keep_logits: bool = False) -> GenerateResult:
+    """Prefill + scan-decode as one compiled program.
+
+    batch["tokens"]: (B, S_prompt).  ``params`` is an argument, never a
+    closure, so the weights stay device buffers and out of the compiled
+    program; one program is compiled per (config, shapes, static args,
+    matmul route).  ``keep_logits`` also returns every step's logits.
+    """
+    return _generate(cfg, params, batch, max_new_tokens=max_new_tokens,
+                     temperature=temperature, key=key, cache_len=cache_len,
+                     keep_logits=keep_logits, route=routing_key())
+
+
+def lower_generate(cfg: ArchConfig, params, batch, **kw):
+    """``generate``'s program for these arguments, lowered (not run)."""
+    return _generate.lower(cfg, params, batch, route=routing_key(), **kw)
+
+
+# ``route`` is never read: the matmul route is process-wide state that
+# tracing reads (``repro.backend.routing_key``), so it is part of the key
+# or a program traced under one route would be reused under another.
+@functools.partial(jax.jit, static_argnames=(
+    "cfg", "max_new_tokens", "temperature", "cache_len", "keep_logits",
+    "route"))
+def _generate(cfg: ArchConfig, params, batch, *, max_new_tokens: int,
+              temperature: float = 0.0, key=None,
+              cache_len: Optional[int] = None, keep_logits: bool = False,
+              route=None) -> GenerateResult:
     mod = family_module(cfg)
     b, s = batch["tokens"].shape
     cache_len = cache_len or (s + max_new_tokens)
@@ -94,21 +128,25 @@ def generate(cfg: ArchConfig, params, batch, *, max_new_tokens: int,
     first = sample(logits, key, temperature)
 
     def body(carry, step_key):
-        tok, cache, pos = carry
+        tok, cache, pos, _ = carry
         logits, cache = mod.decode_step(cfg, params, tok[:, None], cache,
                                         pos)
         nxt = sample(logits, step_key, temperature)
-        return (nxt, cache, pos + 1), (nxt, logits)
+        return (nxt, cache, pos + 1, logits), \
+            (nxt, logits if keep_logits else None)
 
     keys = jax.random.split(key, max_new_tokens - 1) \
         if max_new_tokens > 1 else jnp.zeros((0, 2), jnp.uint32)
-    (last, cache, _), (toks, logit_seq) = jax.lax.scan(
-        body, (first, cache, jnp.int32(s)), keys)
+    (_, cache, _, last_logits), (toks, logit_seq) = jax.lax.scan(
+        body, (first, cache, jnp.int32(s), logits), keys)
     tokens = jnp.concatenate([first[:, None], jnp.moveaxis(toks, 0, 1)],
                              axis=1)
-    logits_last = (logit_seq[-1] if max_new_tokens > 1 else logits)
-    return GenerateResult(tokens=tokens, logits_last=logits_last,
-                          steps=max_new_tokens)
+    all_logits = None
+    if keep_logits:
+        all_logits = jnp.concatenate(
+            [logits[:, None], jnp.moveaxis(logit_seq, 0, 1)], axis=1)
+    return GenerateResult(tokens=tokens, logits_last=last_logits,
+                          steps=max_new_tokens, logits=all_logits)
 
 
 # ---------------------------------------------------------------------------

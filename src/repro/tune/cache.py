@@ -64,7 +64,7 @@ def save_cache(platform_name: str, entries: dict,
     path = cache_path(platform_name, cache_dir)
     path.parent.mkdir(parents=True, exist_ok=True)
     path.write_text(dump_cache(platform_name, entries))
-    _MEMO.pop((platform_name, str(path.parent)), None)
+    clear_memo()
     return path
 
 
@@ -85,6 +85,7 @@ def load_cache(platform_name: str,
 
 
 _MEMO: "dict[tuple[str, str], dict]" = {}
+_GENERATION = 0
 
 
 def lookup(platform_name: str, bucket: str,
@@ -105,4 +106,12 @@ def lookup(platform_name: str, bucket: str,
 
 
 def clear_memo() -> None:
+    global _GENERATION
     _MEMO.clear()
+    _GENERATION += 1
+
+
+def generation() -> int:
+    """Bumped whenever the memoized caches are dropped, so a lookup may
+    answer differently than before (``repro.backend.routing_key``)."""
+    return _GENERATION

@@ -417,6 +417,20 @@ class TestShardedParity:
         acc = shard_map_gemm(a, b, 4, dim="m", bounds=spans)
         assert (np.asarray(acc) == ref).all()
 
+    def test_even_split_needs_devices_or_explicit_loop(self):
+        """More units than devices is an error for ``shard_map_gemm``;
+        the per-span loop is a separate call, and nothing falls back
+        quietly."""
+        from repro.distributed.sharding import shard_map_gemm, sliced_gemm
+        n = jax.device_count() + 1
+        a, b = int8_pair(jax.random.PRNGKey(4), 8 * n, 64, 64)
+        with pytest.raises(ValueError, match="devices"):
+            shard_map_gemm(a, b, n, dim="m")
+        ref = np.asarray(cute_matmul(a, b, backend="xla"))
+        for dim in ("m", "n"):
+            acc = sliced_gemm(a, b, n, dim=dim)
+            assert (np.asarray(acc) == ref).all()
+
 
 class TestClusterBackend:
     def test_capability_flags(self):

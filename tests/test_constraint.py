@@ -8,7 +8,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import constraint
 from repro.core.config import CASE_STUDY, PLATFORM_2TOPS, MatrixUnitConfig, \
     scaled_config, scaling_sweep
-from repro.core.hardware import GIGA, TERA
+from repro.core.hardware import GIGA, TERA, TPU_V5E
 from repro.core.precision import DataType
 
 
@@ -71,7 +71,9 @@ class TestEq2:
 class TestTpuTiles:
     def test_solved_tile_fits_vmem_and_saturates(self):
         tc = constraint.solve_tiles(DataType.BF16)
-        assert tc.vmem_bytes <= 0.5 * 128 * 2**20
+        # within half the compiler's scoped-VMEM limit, not the chip's
+        # 128 MiB of physical VMEM
+        assert tc.vmem_bytes <= 0.5 * TPU_V5E.scoped_vmem_bytes
         assert tc.compute_bound
 
     def test_int8_needs_bigger_tiles_than_bf16(self):

@@ -13,10 +13,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh, AxisType
 
 from repro.core import hlo_cost
 from repro.distributed import logical, sharding
-from repro.launch.mesh import compat_abstract_mesh, compat_make_mesh
 from repro.models.base import ArchConfig
 
 
@@ -24,7 +24,8 @@ def _mesh2x2():
     devs = jax.devices()
     if len(devs) < 4:
         return None
-    return compat_make_mesh((2, 2), ("data", "model"))
+    return jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 class TestLogicalRules:
@@ -34,7 +35,7 @@ class TestLogicalRules:
 
     def test_divisibility_fallback(self):
         # AbstractMesh carries the axis sizes without needing 16 devices.
-        mesh = compat_abstract_mesh((16,), ("model",))
+        mesh = AbstractMesh((16,), ("model",))
         with logical.use_rules(mesh, {"heads": "model"}):
             # 7 heads cannot shard 16 ways -> replicate (gemma2-2b case).
             spec = logical.spec_for((7,), ("heads",))
@@ -44,7 +45,7 @@ class TestLogicalRules:
             assert spec == jax.sharding.PartitionSpec("model")
 
     def test_missing_axis_partial_tuple(self):
-        mesh = compat_make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         with logical.use_rules(mesh, {"batch": ("pod", "data")}):
             spec = logical.spec_for((8, 4), ("batch", None))
             assert spec[0] == "data"      # pod silently dropped
@@ -58,7 +59,8 @@ class TestParamShardings:
         mod = family_module(cfg)
         params = jax.eval_shape(lambda k: mod.init(cfg, k),
                                 jax.random.PRNGKey(0))
-        mesh = compat_make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         sh = sharding.param_shardings(params, mesh)
         flat = jax.tree_util.tree_flatten_with_path(sh)[0]
         # every leaf got a NamedSharding
@@ -75,7 +77,8 @@ class TestParamShardings:
                                 jax.random.PRNGKey(0))
         opt = jax.eval_shape(lambda p: adamw.init(adamw.AdamWConfig(), p),
                              params)
-        mesh = compat_make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ps = sharding.param_shardings(params, mesh)
         ms = sharding.param_shardings(opt["mu"], mesh)
         p_leaves = jax.tree.leaves(ps)
@@ -133,14 +136,14 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType
 
     out = {}
 
     # ---- collective matmul == reference -------------------------------
     from repro.distributed.collective_matmul import (
         collective_matmul, allgather_matmul_reference)
-    mesh = compat_make_mesh((8,), ("model",))
+    mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
     w = jax.random.normal(jax.random.PRNGKey(1), (32, 64))
     y = collective_matmul(x, w, mesh)
@@ -156,7 +159,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     ref_kernel = cute_matmul(x, w, backend="xla")
     out["cmm_vs_kernel_err"] = float(
         jnp.abs(y - ref_kernel).max() / (jnp.abs(ref_kernel).max() + 1e-9))
-    # int8 through the same mesh shim: bit-exact against the kernel path
+    # int8 through the same mesh: bit-exact against the kernel path
     xi = jax.random.randint(jax.random.PRNGKey(5), (64, 32), -8, 8,
                             jnp.int8).astype(jnp.int32)
     wi = jax.random.randint(jax.random.PRNGKey(6), (32, 64), -8, 8,
@@ -171,7 +174,8 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     from repro.models.moe import moe_init, moe_apply, moe_apply_local
     from repro.models.moe import moe_capacity
     cfg = get_config("olmoe-1b-7b", reduced=True).with_(dtype=jnp.float32)
-    mesh2 = compat_make_mesh((2, 4), ("data", "model"))
+    mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2)
     p = moe_init(cfg, jax.random.PRNGKey(0))
     xx = jax.random.normal(jax.random.PRNGKey(2), (4, 16, cfg.d_model))
     y_sharded = moe_apply(cfg, p, xx, mesh=mesh2)
@@ -184,7 +188,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
 
     # ---- pipeline parallelism == sequential apply ----------------------
     from repro.distributed.pipeline import pipeline_apply, stage_slice
-    meshp = compat_make_mesh((4,), ("pp",))
+    meshp = jax.make_mesh((4,), ("pp",), axis_types=(AxisType.Auto,))
     L, D = 8, 16
     ws = jax.random.normal(jax.random.PRNGKey(3), (L, D, D)) / jnp.sqrt(D)
 

@@ -54,8 +54,8 @@ class TestCheckpoint:
         mgr = CheckpointManager(str(tmp_path))
         tree = {"w": jnp.arange(16.0).reshape(4, 4)}
         mgr.save(1, tree)
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((1,), ("data",))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         sh = {"w": NamedSharding(mesh, P("data", None))}
         restored, _ = mgr.restore(1, tree, shardings=sh)
         np.testing.assert_array_equal(np.asarray(restored["w"]),

@@ -7,7 +7,8 @@ import pytest
 
 from repro.configs.registry import concrete_batch, get_config
 from repro.models.base import family_module
-from repro.serving.engine import GenerateResult, ServingEngine, generate
+from repro.serving.engine import (GenerateResult, ServingEngine, generate,
+                                  lower_generate)
 
 
 def _cfg(name="yi-6b"):
@@ -58,6 +59,43 @@ def test_temperature_sampling_runs(model):
                         & (res.tokens < cfg.padded_vocab)))
 
 
+@pytest.mark.parametrize("keep_logits", [False, True])
+def test_generate_logits_match_last_step(model, keep_logits):
+    """``logits_last`` is the last step's logits; the per-step stack is
+    returned only on request."""
+    cfg, mod, params = model
+    prompt = concrete_batch(cfg, 2, 8, "prefill")
+    res = generate(cfg, params, prompt, max_new_tokens=3,
+                   keep_logits=keep_logits)
+    assert res.logits_last.shape == (2, cfg.padded_vocab)
+    if not keep_logits:
+        assert res.logits is None
+        return
+    assert res.logits.shape == (2, 3, cfg.padded_vocab)
+    np.testing.assert_array_equal(np.asarray(res.logits[:, -1]),
+                                  np.asarray(res.logits_last))
+    np.testing.assert_array_equal(
+        np.asarray(jnp.argmax(res.logits, axis=-1)), np.asarray(res.tokens))
+
+
+def test_generate_recompiles_when_matmul_route_changes(model):
+    """The matmul route is process-wide state read while tracing:
+    switching it must give a new program, and switching back the old one."""
+    from repro import backend
+    cfg, mod, params = model
+    prompt = concrete_batch(cfg, 1, 8, "prefill")
+    xla = lower_generate(cfg, params, prompt, max_new_tokens=2).as_text()
+    prev = backend.set_default_matmul_backend("pallas")
+    try:
+        pallas = lower_generate(cfg, params, prompt,
+                                max_new_tokens=2).as_text()
+    finally:
+        backend.set_default_matmul_backend(prev)
+    again = lower_generate(cfg, params, prompt, max_new_tokens=2).as_text()
+    assert pallas != xla
+    assert again == xla
+
+
 def test_serving_engine_batches_requests(model):
     cfg, mod, params = model
     eng = ServingEngine(cfg, params, max_batch=2, cache_len=64)
@@ -82,3 +120,26 @@ def test_generate_on_stateful_family():
         expect = jnp.argmax(logits[:, 8 + i - 1], axis=-1)
         np.testing.assert_array_equal(np.asarray(res.tokens[:, i]),
                                       np.asarray(expect))
+
+
+def _serve(argv, monkeypatch):
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    serve.main(argv)
+
+
+def test_serve_launcher_fits_default_prompt_to_decoder_positions(
+        monkeypatch, capsys):
+    """Learned decoder positions (whisper) cap the default prompt: the
+    reduced encdec config serves at the launcher's defaults."""
+    _serve(["--arch", "whisper-tiny", "--reduced", "--requests", "2",
+            "--max-new", "2"], monkeypatch)
+    assert "served 2 requests, 4 tokens" in capsys.readouterr().out
+
+
+def test_serve_launcher_refuses_prompt_past_decoder_positions(
+        monkeypatch, capsys):
+    with pytest.raises(SystemExit):
+        _serve(["--arch", "whisper-tiny", "--reduced", "--requests", "1",
+                "--prompt-len", "255", "--max-new", "2"], monkeypatch)
+    assert "exceeds the 256 decoder positions" in capsys.readouterr().err

@@ -1,0 +1,126 @@
+"""The device path's Pallas kernels compile for a TPU v5e at real widths.
+
+Each test compiles one kernel with the TPU compiler installed on the
+host, for a v5e that is described and not attached, so Mosaic refusals
+(block shapes, VMEM, unsupported ops) surface without a chip.  Nothing
+runs: these say nothing about results or speed.  Widths are yi-6b's
+(d_model 4096, d_ff 11008, 32 query / 4 KV heads of 128) and, for the
+kernels yi-6b does not use, those of the configs that do (olmoe-1b-7b
+experts, recurrentgemma-2b RG-LRU, rwkv6-7b heads).
+
+The topology is described inside a fixture only: the TPU library may be
+loaded by one process at a time, so it is never touched while modules
+are imported, and only the worker that runs this file loads it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.fusion import Epilogue, EpilogueOperands
+from repro.core.task import BiasType
+from repro.kernels.attention.ops import flash_attention
+from repro.kernels.matmul.ops import fused_matmul
+from repro.kernels.moe.ops import grouped_matmul
+from repro.kernels.quant.ops import quantize_rowwise
+from repro.kernels.rglru.ops import rglru_scan
+from repro.kernels.rwkv6.ops import rwkv6_scan
+
+BF, I8, F32 = jnp.bfloat16, jnp.int8, jnp.float32
+TOKENS = 2048                      # 4 prompts of 512 tokens
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described v5e chip, with the persistent compilation cache
+    off: an entry written here could not be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _glu(act="silu", **kw):
+    return Epilogue(activation=act, glu=True, out_dtype=BF, **kw)
+
+
+#: name -> (kernel call, argument (shape, dtype) list)
+CASES = {
+    "matmul_bf16_glu_4096x22016": (
+        lambda a, b: fused_matmul(a, b, epilogue=_glu(), interpret=False),
+        [((TOKENS, 4096), BF), ((4096, 2 * 11008), BF)]),
+    "matmul_bf16_down_11008x4096": (
+        lambda a, b: fused_matmul(a, b, epilogue=Epilogue(out_dtype=BF),
+                                  interpret=False),
+        [((TOKENS, 11008), BF), ((11008, 4096), BF)]),
+    "matmul_bf16_row_bias_4096x4096": (
+        lambda a, b, bias: fused_matmul(
+            a, b, epilogue=Epilogue(bias_type=BiasType.ROW, out_dtype=BF),
+            operands=EpilogueOperands(bias=bias), interpret=False),
+        [((TOKENS, 4096), BF), ((4096, 4096), BF), ((4096,), BF)]),
+    "matmul_bf16_logits_4096x64000": (
+        lambda a, b: fused_matmul(a, b, epilogue=Epilogue(out_dtype=F32),
+                                  interpret=False),
+        [((8, 4096), BF), ((4096, 64000), BF)]),
+    "matmul_int8_w8a8_4096x11008": (
+        lambda a, b, sa, sb: fused_matmul(
+            a, b, epilogue=Epilogue(has_scale_a=True, has_scale_b=True,
+                                    out_dtype=BF),
+            operands=EpilogueOperands(scale_a=sa, scale_b=sb),
+            interpret=False),
+        [((TOKENS, 4096), I8), ((4096, 11008), I8), ((TOKENS,), F32),
+         ((11008,), F32)]),
+    "matmul_int8_glu_4096x22016": (
+        lambda a, b, sa, sb: fused_matmul(
+            a, b, epilogue=_glu(has_scale_a=True, has_scale_b=True),
+            operands=EpilogueOperands(scale_a=sa, scale_b=sb),
+            interpret=False),
+        [((TOKENS, 4096), I8), ((4096, 2 * 11008), I8), ((TOKENS,), F32),
+         ((2 * 11008,), F32)]),
+    "flash_attention_gqa_32q_4kv_2048": (
+        lambda q, k, v: flash_attention(q, k, v, interpret=False),
+        [((1, 32, 2048, 128), BF), ((1, 4, 2048, 128), BF),
+         ((1, 4, 2048, 128), BF)]),
+    "grouped_matmul_glu_64x2048x2048": (
+        lambda x, w: grouped_matmul(x, w, epilogue=_glu(), interpret=False),
+        [((64, 320, 2048), BF), ((64, 2048, 2 * 1024), BF)]),
+    "grouped_matmul_down_64x1024x2048": (
+        lambda x, w: grouped_matmul(x, w, interpret=False),
+        [((64, 320, 1024), BF), ((64, 1024, 2048), BF)]),
+    "quantize_rowwise_2048x4096": (
+        lambda x: quantize_rowwise(x, interpret=False),
+        [((TOKENS, 4096), F32)]),
+    "rglru_scan_2560": (
+        lambda log_a, x: rglru_scan(log_a, x, interpret=False),
+        [((1, 2048, 2560), F32), ((1, 2048, 2560), F32)]),
+    "rwkv6_scan_64x64": (
+        lambda r, k, v, lw, u: rwkv6_scan(r, k, v, lw, u, chunk=32,
+                                          interpret=False),
+        [((1, 64, 2048, 64), BF)] * 4 + [((64, 64), F32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, arg_specs = CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in arg_specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
