@@ -10,6 +10,11 @@ Attention offers three implementations:
 * ``pallas`` — the ``kernels/attention`` flash kernel (interpret-mode on
   CPU; the on-chip path on real TPUs).
 * ``dense``  — the reference oracle, for tiny smoke tests only.
+
+The layer helpers run under ``jax.named_scope``s (``embed``, ``norm``,
+``qkv``, ``cache_update``, ``attention``, ``attn_out``, ``mlp``,
+``head``), so every family that calls them gets its ops named in the
+compiled program's ``op_name`` metadata; scopes change nothing else.
 """
 
 from __future__ import annotations
@@ -46,6 +51,7 @@ def embed_init(key, shape, dtype):
 # Norms.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("norm")
 def rmsnorm(x, w, eps: float = 1e-6, unit_offset: bool = False):
     dt = x.dtype
     xf = x.astype(jnp.float32)
@@ -170,6 +176,7 @@ def attention_xla_chunked(q, k, v, *, sm_scale, causal=True, window=0,
     return out.astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def attention(cfg: ArchConfig, q, k, v, *, causal=True, window=0,
               softcap=None, q_start=0, sm_scale=None):
     """Backend-dispatching attention. q: (B, H, S, D), k/v: (B, Hkv, S, D)."""
@@ -213,6 +220,7 @@ def attn_init(cfg: ArchConfig, key, *, d_in: Optional[int] = None):
     return p
 
 
+@jax.named_scope("qkv")
 def qkv_project(cfg: ArchConfig, p, x, positions):
     """x: (B, S, d) -> q (B, H, S, hd), k/v (B, Hkv, S, hd) with RoPE."""
     b, s, _ = x.shape
@@ -234,6 +242,7 @@ def qkv_project(cfg: ArchConfig, p, x, positions):
     return q, k, v
 
 
+@jax.named_scope("attn_out")
 def attn_out(cfg: ArchConfig, p, ctx):
     """ctx: (B, H, S, hd) -> (B, S, d)."""
     b, h, s, hd = ctx.shape
@@ -266,6 +275,7 @@ def mlp_init(cfg: ArchConfig, key, d_ff: Optional[int] = None):
     }
 
 
+@jax.named_scope("mlp")
 def mlp_apply(cfg: ArchConfig, p, x):
     h = linear(x, p["wi"], activation=cfg.mlp_activation, glu=cfg.mlp_glu,
                backend=_mm_backend(cfg))
@@ -277,6 +287,7 @@ def mlp_apply(cfg: ArchConfig, p, x):
 # Embedding / logits.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ArchConfig, embedding, tokens):
     x = embedding[tokens]
     if cfg.embed_scale:
@@ -284,6 +295,7 @@ def embed_tokens(cfg: ArchConfig, embedding, tokens):
     return x
 
 
+@jax.named_scope("head")
 def logits_out(cfg: ArchConfig, params, x):
     w = (params["embedding"].T if cfg.tie_embeddings
          else params["lm_head"])
@@ -297,6 +309,7 @@ def logits_out(cfg: ArchConfig, params, x):
 # KV cache helpers (dense ring buffer, optionally quantized dtype).
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("cache_update")
 def cache_update(k_cache, v_cache, k_new, v_new, pos):
     """Write (B, Hkv, S_new, D) at position ``pos`` along the S axis."""
     k_cache = jax.lax.dynamic_update_slice_in_dim(

@@ -69,10 +69,11 @@ def block_apply(cfg: ArchConfig, p, x, *, positions, window: int,
         new_kv = (k_cache, v_cache)
         if q.shape[2] == 1:                      # decode: one new token
             from repro.kernels.attention.ops import decode_attention
-            ctx = decode_attention(
-                q, k_cache, v_cache, cache_pos + 1,
-                sm_scale=cfg.sm_scale, window=window,
-                softcap=cfg.attn_softcap)
+            with jax.named_scope("attention"):
+                ctx = decode_attention(
+                    q, k_cache, v_cache, cache_pos + 1,
+                    sm_scale=cfg.sm_scale, window=window,
+                    softcap=cfg.attn_softcap)
         else:                                    # prefill writes + attends
             ctx = cm.attention(cfg, q, k, v, causal=True, window=window)
     else:

@@ -124,8 +124,9 @@ def _generate(cfg: ArchConfig, params, batch, *, max_new_tokens: int,
     key = key if key is not None else jax.random.PRNGKey(0)
 
     cache = mod.init_cache(cfg, b, cache_len)
-    logits, cache = mod.prefill(cfg, params, batch, cache)
-    first = sample(logits, key, temperature)
+    with jax.named_scope("prefill"):
+        logits, cache = mod.prefill(cfg, params, batch, cache)
+        first = sample(logits, key, temperature)
 
     def body(carry, step_key):
         tok, cache, pos, _ = carry
@@ -137,8 +138,11 @@ def _generate(cfg: ArchConfig, params, batch, *, max_new_tokens: int,
 
     keys = jax.random.split(key, max_new_tokens - 1) \
         if max_new_tokens > 1 else jnp.zeros((0, 2), jnp.uint32)
-    (_, cache, _, last_logits), (toks, logit_seq) = jax.lax.scan(
-        body, (first, cache, jnp.int32(s), logits), keys)
+    # The scope covers the loop itself, so the copies its body makes
+    # outside any layer are put down to decode too.
+    with jax.named_scope("decode"):
+        (_, cache, _, last_logits), (toks, logit_seq) = jax.lax.scan(
+            body, (first, cache, jnp.int32(s), logits), keys)
     tokens = jnp.concatenate([first[:, None], jnp.moveaxis(toks, 0, 1)],
                              axis=1)
     all_logits = None
@@ -312,7 +316,11 @@ class ServingEngine:
     engine reports into — by default the process registry, which starts
     *disabled* so planning/pricing pay nothing; serving entry points
     (``launch/serve.py --metrics-out``, ``benchmarks/record.py``) enable
-    it or pass their own.
+    it or pass their own.  The device path (:meth:`run`) counts
+    ``serving_batches_total``, ``serving_rows_total`` (real requests),
+    ``serving_pad_tokens_total`` (left-padding tokens stacked) and
+    ``serving_new_programs_total{rows,prompt_len}`` (batches whose
+    ``generate`` call compiled or loaded a new program).
     """
 
     def __init__(self, cfg: ArchConfig, params, max_batch: int = 8,
@@ -559,6 +567,7 @@ class ServingEngine:
     def run(self, max_new_tokens: int = 32, temperature: float = 0.0):
         """Drain the queue in padded batches; returns list of token arrays."""
         out = []
+        m = self.metrics
         while self._queue:
             chunk, self._queue = (self._queue[: self.max_batch],
                                   self._queue[self.max_batch:])
@@ -574,9 +583,18 @@ class ServingEngine:
                 batch["vision_embeds"] = jnp.zeros(
                     (toks.shape[0], self.cfg.vision_prefix,
                      self.cfg.d_model), jnp.float32)
+            programs = _generate._cache_size() if m.enabled else 0
             res = generate(self.cfg, self.params, batch,
                            max_new_tokens=max_new_tokens,
                            temperature=temperature,
                            cache_len=self.cache_len)
             out.extend(list(res.tokens))
+            if m.enabled:
+                m.counter("serving_batches_total").inc()
+                m.counter("serving_rows_total").inc(len(chunk))
+                m.counter("serving_pad_tokens_total").inc(
+                    sum(s - int(t.shape[-1]) for t in chunk))
+                if _generate._cache_size() > programs:
+                    m.counter("serving_new_programs_total",
+                              rows=len(chunk), prompt_len=s).inc()
         return out

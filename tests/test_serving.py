@@ -107,6 +107,69 @@ def test_serving_engine_batches_requests(model):
         assert o.shape == (3,)
 
 
+LAYER_SCOPES = {"embed", "norm", "qkv", "cache_update", "attention",
+                "attn_out", "mlp", "head"}
+
+
+def test_every_compiled_dot_lies_in_one_step_and_one_layer_scope():
+    """The compiled program names its dots by ``jax.named_scope``:
+    each under ``prefill`` or ``decode`` and exactly one layer scope."""
+    import re
+    cfg = get_config("yi-6b", reduced=True)
+    mod = family_module(cfg)
+    params = jax.eval_shape(lambda k: mod.init(cfg, k),
+                            jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 16), jnp.int32)}
+    text = lower_generate(cfg, params, batch, max_new_tokens=4,
+                          cache_len=20).compile().as_text()
+    dots = re.findall(r"= \S+ (?:dot|convolution)\(.*op_name=\"([^\"]*)\"",
+                      text)
+    assert len(dots) >= 2 * 8        # prefill and decode, 8 dots a layer
+    layers = []
+    for name in dots:
+        parts = name.split("/")
+        assert (("prefill" in parts) + ("decode" in parts)) == 1, name
+        inner = [p for p in parts if p in LAYER_SCOPES]
+        assert len(inner) == 1, name
+        layers.append(inner[0])
+    assert set(layers) == {"qkv", "attention", "attn_out", "mlp", "head"}
+
+
+def test_engine_counts_batches_rows_padding_and_new_programs(model):
+    from repro.obs import MetricsRegistry
+    from repro.serving.engine import _generate
+    cfg, mod, params = model
+    reg = MetricsRegistry()
+    eng = ServingEngine(cfg, params, max_batch=2, cache_len=64, metrics=reg)
+    lengths = (5, 7, 6, 4, 3)
+    _generate.clear_cache()
+    for rounds in (1, 2):
+        for n in lengths:
+            eng.submit(jnp.arange(n) % cfg.vocab_size)
+        assert len(eng.run(max_new_tokens=2)) == len(lengths)
+        c = reg.snapshot()["counters"]
+        # Batches [5, 7], [6, 4], [3]: 2 + 2 + 0 padding tokens.
+        assert c["serving_batches_total"][0]["value"] == 3 * rounds
+        assert c["serving_rows_total"][0]["value"] == 5 * rounds
+        assert c["serving_pad_tokens_total"][0]["value"] == 4 * rounds
+        # Three shapes, each a new program in the first round only.
+        progs = {(x["labels"]["rows"], x["labels"]["prompt_len"]):
+                 x["value"] for x in c["serving_new_programs_total"]}
+        assert progs == {("2", "7"): 1, ("2", "6"): 1, ("1", "3"): 1}
+
+
+def test_a_disabled_registry_records_nothing(model):
+    from repro.obs import MetricsRegistry
+    cfg, mod, params = model
+    reg = MetricsRegistry(enabled=False)
+    eng = ServingEngine(cfg, params, max_batch=2, cache_len=64, metrics=reg)
+    for n in (5, 7, 6):
+        eng.submit(jnp.arange(n) % cfg.vocab_size)
+    assert len(eng.run(max_new_tokens=2)) == 3
+    reg.enable()
+    assert reg.snapshot()["counters"] == {}
+
+
 def test_generate_on_stateful_family():
     """RWKV-family generation exercises the O(1)-state serving path."""
     cfg = _cfg("rwkv6-7b")
