@@ -11,6 +11,9 @@ Attention offers three implementations:
   CPU; the on-chip path on real TPUs).
 * ``dense``  — the reference oracle, for tiny smoke tests only.
 
+A cached prefill takes ``prefill_attention`` instead, which picks its
+route by platform alone.
+
 The layer helpers run under ``jax.named_scope``s (``embed``, ``norm``,
 ``qkv``, ``cache_update``, ``attention``, ``attn_out``, ``mlp``,
 ``head``), so every family that calls them gets its ops named in the
@@ -195,6 +198,42 @@ def attention(cfg: ArchConfig, q, k, v, *, causal=True, window=0,
                                  window=window, softcap=softcap,
                                  q_start=q_start, chunk=cfg.attn_chunk,
                                  pv_bf16=cfg.attn_pv_bf16)
+
+
+@jax.named_scope("attention")
+def prefill_attention(cfg: ArchConfig, q, k, v, *, window=0):
+    """Causal self-attention of a cached prefill, which no gradient
+    passes through.
+
+    Where Pallas kernels compile (``resolve_interpret`` is false: a
+    TPU), the flash kernel, which fetches and computes only the causal
+    (and window) band of KV blocks; elsewhere the chunked scan, since a
+    whole model under the kernel interpreter is too slow.  ``cfg.backend``
+    is not read.  Each traced call adds its blocks to
+    ``attention_prefill_blocks_total{route, kind}`` (``flash`` or
+    ``chunked``; ``computed`` or ``skipped``): the flash grid's steps
+    inside and outside the band, or the chunked scan's (B·Hkv, chunk)
+    blocks, all computed."""
+    from repro.kernels import resolve_interpret
+    from repro.kernels.attention.ops import band_blocks, flash_attention
+    from repro.obs import default_registry
+    flash = not resolve_interpret()
+    if flash:
+        blocks = band_blocks(q.shape, k.shape, window=window)
+    else:
+        b, hkv, sk = k.shape[:3]
+        blocks = (b * hkv * -(-sk // min(cfg.attn_chunk, sk)), 0)
+    for kind, n in zip(("computed", "skipped"), blocks):
+        default_registry().counter(
+            "attention_prefill_blocks_total",
+            route="flash" if flash else "chunked", kind=kind).inc(n)
+    if flash:
+        return flash_attention(q, k, v, sm_scale=cfg.sm_scale, causal=True,
+                               window=window, softcap=cfg.attn_softcap)
+    return attention_xla_chunked(
+        q, k, v, sm_scale=cfg.sm_scale, causal=True, window=window,
+        softcap=cfg.attn_softcap, chunk=cfg.attn_chunk,
+        pv_bf16=cfg.attn_pv_bf16)
 
 
 # ---------------------------------------------------------------------------

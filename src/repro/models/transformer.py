@@ -75,7 +75,7 @@ def block_apply(cfg: ArchConfig, p, x, *, positions, window: int,
                     sm_scale=cfg.sm_scale, window=window,
                     softcap=cfg.attn_softcap)
         else:                                    # prefill writes + attends
-            ctx = cm.attention(cfg, q, k, v, causal=True, window=window)
+            ctx = cm.prefill_attention(cfg, q, k, v, window=window)
     else:
         ctx = cm.attention(cfg, q, k, v, causal=True, window=window)
 

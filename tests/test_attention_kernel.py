@@ -67,6 +67,91 @@ def test_bf16(case=CASES[1]):
     _check(out, ref, rtol=4e-2)
 
 
+#: The banded kernel in bf16 against the float32 oracle: grids whose
+#: causal/window band skips KV blocks, with blocks of unequal size.
+BANDED = [
+    # causal GQA, keys not a whole number of blocks (4080 at small size)
+    dict(B=2, H=8, HKV=2, SQ=255, SK=255, D=64, BQ=64, BKV=64),
+    dict(B=1, H=4, HKV=2, SQ=64, SK=192, D=64, q_start=128, BQ=32,
+         BKV=64),
+    dict(B=1, H=4, HKV=4, SQ=256, SK=256, D=64, window=64, BQ=64,
+         BKV=32),
+    dict(B=1, H=4, HKV=2, SQ=128, SK=128, D=64, softcap=50.0, BQ=32,
+         BKV=64),
+    dict(B=1, H=4, HKV=4, SQ=96, SK=200, D=64, causal=False, BQ=32,
+         BKV=64),
+    dict(B=1, H=4, HKV=1, SQ=200, SK=200, D=32, BQ=128, BKV=32),
+    dict(B=1, H=4, HKV=1, SQ=200, SK=200, D=32, BQ=16, BKV=128),
+    dict(B=1, H=8, HKV=4, SQ=160, SK=160, D=32, window=40, softcap=50.0,
+         BQ=32, BKV=16),
+]
+
+
+@pytest.mark.parametrize("case", BANDED, ids=lambda c: str(sorted(c.items())))
+def test_banded_bf16_kernel_vs_oracle(case):
+    q, k, v, kw = _mk(case, jnp.bfloat16)
+    out = flash_attention(q, k, v, block_q=case["BQ"], block_kv=case["BKV"],
+                          **kw)
+    ref = attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
+                        v.astype(jnp.float32), **kw)
+    _check(out, ref, rtol=4e-2)
+
+
+def _pallas_call(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", None)
+            inner = getattr(inner, "jaxpr", inner)
+            found = inner is not None and _pallas_call(inner)
+            if found:
+                return found
+    return None
+
+
+@pytest.mark.parametrize("case", BANDED, ids=lambda c: str(sorted(c.items())))
+def test_kv_index_map_stays_in_the_band(case):
+    """Every grid step's K and V blocks lie in its query block's band,
+    the band holds every block with an unmasked (query, key) pair, and
+    ``band_blocks`` counts the band's steps as computed."""
+    from repro.kernels.attention.ops import band_blocks
+    q, k, v, kw = _mk(case, jnp.bfloat16)
+    blocks = dict(block_q=case["BQ"], block_kv=case["BKV"])
+    eqn = _pallas_call(jax.make_jaxpr(
+        lambda q, k, v: flash_attention(q, k, v, **blocks, **kw))(
+            q, k, v).jaxpr)
+    gm = eqn.params["grid_mapping"]
+    n_bh, n_q, n_kv = gm.grid
+    bq, bkv = case["BQ"], case["BKV"]
+    sq, sk = case["SQ"], case["SK"]
+    qpos = kw.get("q_start", 0) + np.arange(n_q * bq)[:, None]
+    kpos = np.arange(n_kv * bkv)[None, :]
+    mask = (kpos < sk) & (qpos < kw.get("q_start", 0) + sq)
+    if kw.get("causal", True):
+        mask &= kpos <= qpos
+    if kw.get("window", 0):
+        mask &= (qpos - kpos) < kw["window"]
+    needed = mask.reshape(n_q, bq, n_kv, bkv).any(axis=(1, 3))
+
+    computed = 0
+    for iq in range(n_q):
+        visited = set()
+        for bm in gm.block_mappings[1:3]:                  # k, v
+            m = bm.index_map_jaxpr
+            for jk in range(n_kv):
+                _, blk, _ = jax.core.eval_jaxpr(m.jaxpr, m.consts, 0, iq, jk)
+                visited.add(int(blk))
+        band = set(range(min(visited), max(visited) + 1))
+        assert visited == band
+        assert set(np.flatnonzero(needed[iq])) <= band, iq
+        computed += len(band)
+    band_kw = {k_: kw[k_] for k_ in ("causal", "window", "q_start")
+               if k_ in kw}
+    assert band_blocks(q.shape, k.shape, **blocks, **band_kw) == (
+        n_bh * computed, n_bh * (n_q * n_kv - computed))
+
+
 def test_decode_matches_prefix_oracle():
     B, H, HKV, S, D, L = 2, 8, 2, 64, 32, 40
     ks = jax.random.split(jax.random.PRNGKey(1), 3)

@@ -135,6 +135,47 @@ def test_every_compiled_dot_lies_in_one_step_and_one_layer_scope():
     assert set(layers) == {"qkv", "attention", "attn_out", "mlp", "head"}
 
 
+def test_cached_prefill_takes_the_flash_kernel_where_pallas_compiles(
+        model, monkeypatch):
+    """The cached prefill attends through the flash kernel where Pallas
+    compiles (a TPU backend, which ``resolve_interpret`` reads), through
+    the chunked scan elsewhere, and counts the route's blocks; the
+    training forward under ``jax.grad`` keeps the chunked scan."""
+    from repro.kernels.attention.ops import band_blocks
+    from repro.obs import default_registry
+    cfg, mod, params = model
+    batch = concrete_batch(cfg, 2, 12, "prefill")
+    cache = mod.init_cache(cfg, 2, 16)
+
+    def routes():
+        reg.clear()
+        prefill = str(jax.make_jaxpr(
+            lambda p: mod.prefill(cfg, p, batch, cache))(params))
+        c = reg.snapshot()["counters"]["attention_prefill_blocks_total"]
+        grad = str(jax.make_jaxpr(jax.grad(
+            lambda p: mod.forward(cfg, p, batch).sum()))(params))
+        return ("pallas_call" in prefill, "pallas_call" in grad,
+                {(x["labels"]["route"], x["labels"]["kind"]): x["value"]
+                 for x in c})
+
+    reg = default_registry()
+    was = reg.enabled
+    reg.enable()
+    try:
+        hkv, hd = cfg.n_kv_heads, cfg.head_dim
+        assert routes() == (False, False, {
+            ("chunked", "computed"): 2 * hkv * -(-12 // cfg.attn_chunk),
+            ("chunked", "skipped"): 0})
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        computed, skipped = band_blocks((2, cfg.n_heads, 12, hd),
+                                        (2, hkv, 12, hd))
+        assert routes() == (True, False, {("flash", "computed"): computed,
+                                          ("flash", "skipped"): skipped})
+    finally:
+        reg.clear()
+        reg.enabled = was
+
+
 def test_engine_counts_batches_rows_padding_and_new_programs(model):
     from repro.obs import MetricsRegistry
     from repro.serving.engine import _generate

@@ -98,6 +98,17 @@ CASES = {
         lambda q, k, v: flash_attention(q, k, v, interpret=False),
         [((1, 32, 2048, 128), BF), ((1, 4, 2048, 128), BF),
          ((1, 4, 2048, 128), BF)]),
+    # The served prefill shapes (ds67b-6l.prefill, yi6b.decode), causal
+    # at the default blocks: 4080 keys pad to whole blocks, and the last
+    # query block runs past the queries.
+    "flash_attention_prefill_64q_8kv_4080": (
+        lambda q, k, v: flash_attention(q, k, v, interpret=False),
+        [((2, 64, 4080, 128), BF), ((2, 8, 4080, 128), BF),
+         ((2, 8, 4080, 128), BF)]),
+    "flash_attention_prefill_32q_4kv_1024": (
+        lambda q, k, v: flash_attention(q, k, v, interpret=False),
+        [((8, 32, 1024, 128), BF), ((8, 4, 1024, 128), BF),
+         ((8, 4, 1024, 128), BF)]),
     "grouped_matmul_glu_64x2048x2048": (
         lambda x, w: grouped_matmul(x, w, epilogue=_glu(), interpret=False),
         [((64, 320, 2048), BF), ((64, 2048, 2 * 1024), BF)]),
