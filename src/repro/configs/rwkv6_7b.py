@@ -2,7 +2,11 @@
 
 Finch — data-dependent per-channel decay, 64 heads of size 64, DDLerp
 token-shift, squared-ReLU channel mix.  Bounded state ⇒ runs long_500k.
-[arXiv:2404.05892; hf]
+LayerNorm epsilon 1e-5 (``layer_norm_epsilon``; GroupNorm's is that
+times ``head_size_divisor`` 8 squared).  The low-rank widths are the
+published code's for d_model 4096: 64 for the token mixes, 128 for the
+decay (32 and 64 at the smaller Finch sizes).
+[arXiv:2404.05892; hf RWKV/v6-Finch-7B-HF]
 """
 
 from repro.models.base import ArchConfig, RwkvConfig
@@ -17,7 +21,9 @@ CONFIG = ArchConfig(
     head_dim=64,
     d_ff=14336,
     vocab_size=65536,
-    rwkv=RwkvConfig(head_size=64, lora_mix=32, lora_decay=64),
+    rms_eps=1e-5,
+    mlp_activation="relu2",
+    rwkv=RwkvConfig(head_size=64, lora_mix=64, lora_decay=128),
 )
 
 

@@ -49,7 +49,6 @@ class RwkvConfig:
     head_size: int = 64
     lora_mix: int = 32                 # DDLerp low-rank dim
     lora_decay: int = 64
-    lora_gate: int = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,9 +133,10 @@ class ArchConfig:
             rw = self.rwkv
             per = (5 * d * d                          # r, k, v, g, out proj
                    + 10 * d * rw.lora_mix             # DDLerp W1/W2
-                   + 2 * d * rw.lora_decay + 2 * d * rw.lora_gate
-                   + 2 * d * self.d_ff + d * d)       # channel mix (k, v, r)
-            return embed + self.n_layers * per
+                   + 2 * d * rw.lora_decay            # decay W1/W2
+                   + 2 * d * self.d_ff + d * d        # channel mix (k, v, r)
+                   + 16 * d)   # mixes, decay base, bonus, three norms
+            return embed + 4 * d + self.n_layers * per
         attn = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
         glu_mult = 2 if self.mlp_glu else 1
         dense_mlp = d * self.d_ff * glu_mult + self.d_ff * d

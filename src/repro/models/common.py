@@ -64,6 +64,7 @@ def rmsnorm(x, w, eps: float = 1e-6, unit_offset: bool = False):
     return (y * scale).astype(dt)
 
 
+@jax.named_scope("norm")
 def layernorm(x, w, b, eps: float = 1e-5):
     dt = x.dtype
     xf = x.astype(jnp.float32)

@@ -6,20 +6,38 @@ Faithful block structure (arXiv:2404.05892):
     the WKV recurrence (kernels/rwkv6); per-head GroupNorm; SiLU gate;
     output projection.
   * Channel-mix: token-shift lerp, squared-ReLU FFN with a sigmoid
-    receptance gate.
+    receptance gate (the activation is ``cfg.mlp_activation``).
+
+LayerNorms take their epsilon from ``cfg.rms_eps``; the per-head
+GroupNorm takes it times ``head_size_divisor`` (8) squared, as the
+published code does.  The one departure from the paper: the decay exponent is
+clipped to [-8, 6] before ``w = exp(-exp(.))``.
 
 Paper applicability (DESIGN.md §4): the recurrence is vector work — all
 projections still flow through ``cute_matmul``; the chunked WKV turns
 the state update into MXU-sized outer products.
 
-The XLA (distributed/dry-run) path uses ``rwkv6_chunked_jnp`` — the same
-chunked math as the Pallas kernel in pure jnp under ``lax.scan`` so
-cost_analysis sees its FLOPs; the Pallas kernel is selected by
-``cfg.backend == 'pallas'``.
+Training's ``forward`` picks the WKV by ``cfg.backend``: the Pallas
+kernel for ``pallas``, the per-token oracle for ``dense``, else
+``rwkv6_chunked_jnp`` (the kernel's chunked math in jnp under
+``lax.scan``, so cost_analysis sees its FLOPs).  Serving does not read
+``cfg.backend``: prefill streams the prompt through the state in slices
+of ``PREFILL_SLICE`` tokens through the chunked form, and
+decode takes the exact per-token step.
+
+Layer scopes, for the device trace: ``embed``, ``head``; ``norm`` (the
+LayerNorms); ``qkv`` (token shift, DDLerp, the r/k/v/g projections and
+the decay LoRA); ``attention`` (the WKV recurrence); ``attn_out``
+(GroupNorm, gate, ``w_o``); ``mlp`` (the channel mix); ``cache_update``
+(writing the shift states back; the WKV state's write is the
+recurrence's, in ``attention``).  Each traced serving call adds to
+``rwkv_wkv_calls_total{route, step}``: the prefill's slices, or one
+decode step.
 """
 
 from __future__ import annotations
 
+import functools
 import sys
 
 import jax
@@ -31,6 +49,19 @@ from repro.models import common as cm
 from repro.models.base import ArchConfig, register_family
 
 _N_MIX = 5     # r, k, v, g, w
+#: a layer's states in the serving cache, with the layer scopes that
+#: read and write them.  The WKV state is written back in ``attention``:
+#: the compiler fuses the recurrence's update into that in-place write,
+#: which would otherwise take the recurrence out of its scope.
+_STATES = (("tm_shift", "qkv", "cache_update"),
+           ("cm_shift", "mlp", "cache_update"),
+           ("wkv", "attention", "attention"))
+HEAD_SIZE_DIVISOR = 8          # the published GroupNorm eps is eps * 8 ** 2
+#: serving prefill's tokens per slice: the longest whose program fits a
+#: v5e at the chip cell's batch of 128 (memory_analysis, PERF.md)
+PREFILL_SLICE = 128
+#: the chunked WKV's chunk in serving prefill (chip sweep, PERF.md)
+PREFILL_WKV_CHUNK = 16
 
 
 # ---------------------------------------------------------------------------
@@ -82,14 +113,86 @@ def rwkv6_chunked_jnp(r, k, v, lw, u, *, chunk: int = 64,
     return o.astype(r.dtype), state
 
 
-def _wkv(cfg: ArchConfig, r, k, v, lw, u):
+# The serving cache holds a layer's WKV state lane-dense, as (B, C, d):
+# S[b, c, h * C + e] is head h's entry (key channel c, value channel e).
+# A (B, H, C, C) array of float32 with C = 64 would fill half of each
+# (8, 128) tile of the TPU's memory and so take twice its size.
+
+def _state_heads(s, h):
+    """(B, C, d) -> (B, H, C, C)."""
+    b, c, _ = s.shape
+    return s.reshape(b, c, h, c).transpose(0, 2, 1, 3)
+
+
+def _state_flat(s):
+    """(B, H, C, C) -> (B, C, d)."""
+    b, h, c, _ = s.shape
+    return s.transpose(0, 2, 1, 3).reshape(b, c, h * c)
+
+
+def _heads(z, h):
+    """(B, T, d) -> (B, H, T, C)."""
+    b, t, d = z.shape
+    return z.reshape(b, t, h, d // h).transpose(0, 2, 1, 3)
+
+
+def _unheads(o):
+    """(B, H, T, C) -> (B, T, d)."""
+    b, h, t, c = o.shape
+    return o.transpose(0, 2, 1, 3).reshape(b, t, h * c)
+
+
+# A WKV route: (r, k, v, lw: (B, T, d); u: (H, C); the state (B, C, d) or
+# None for zeros) -> (o: (B, T, d), the state after the last token).
+
+def _chunked(r, k, v, lw, u, state, chunk: int = 64):
+    h = u.shape[0]
+    o, s = rwkv6_chunked_jnp(
+        *(_heads(z, h) for z in (r, k, v, lw)), u, chunk=chunk,
+        initial_state=None if state is None else _state_heads(state, h))
+    return _unheads(o), _state_flat(s)
+
+
+def _recurrent(r, k, v, lw, u, state):
+    """The exact per-token recurrence on the lane-dense state:
+    o_t = r_t (S + diag(u) k_t^T v_t), then S = diag(w_t) S + k_t^T v_t."""
+    b, t, d = r.shape
+    h, c = u.shape
+
+    def per_key(z):
+        """z[..., h * C + c] -> (..., C, d), along every value channel."""
+        lead = z.shape[:-1]
+        z = jnp.moveaxis(z.reshape(*lead, h, c, 1), -2, -3)
+        return jnp.broadcast_to(z, (*lead, c, h, c)).reshape(*lead, c, d)
+
+    uu = per_key(u.reshape(d).astype(jnp.float32))
+
+    def step(s, inp):
+        r_t, k_t, v_t, lw_t = (z.astype(jnp.float32) for z in inp)
+        kv = per_key(k_t) * v_t[:, None, :]
+        o = jnp.sum(per_key(r_t) * (s + uu * kv), axis=1)
+        return per_key(jnp.exp(lw_t)) * s + kv, o
+
+    if state is None:
+        state = jnp.zeros((b, c, d), jnp.float32)
+    state, o = jax.lax.scan(step, state, tuple(
+        jnp.moveaxis(z, 1, 0) for z in (r, k, v, lw)))
+    return jnp.moveaxis(o, 0, 1).astype(r.dtype), state
+
+
+def _train_wkv(cfg: ArchConfig):
+    """Training's WKV route, by ``cfg.backend``; starts from no state."""
     if cfg.backend == "pallas":
         from repro.kernels.rwkv6.ops import rwkv6_scan
-        return rwkv6_scan(r, k, v, lw, u, chunk=32)
+
+        def pallas(r, k, v, lw, u, _):
+            h = u.shape[0]
+            return _unheads(rwkv6_scan(*(_heads(z, h) for z in (r, k, v, lw)),
+                                       u, chunk=32)), None
+        return pallas
     if cfg.backend == "dense":
-        from repro.kernels.rwkv6.ref import rwkv6_ref
-        return rwkv6_ref(r, k, v, lw, u)[0]
-    return rwkv6_chunked_jnp(r, k, v, lw, u)[0]
+        return _recurrent
+    return _chunked
 
 
 # ---------------------------------------------------------------------------
@@ -158,71 +261,85 @@ def _shift(x, last=None):
     return jnp.concatenate([pad, x[:, :-1]], axis=1)
 
 
-def time_mix(cfg: ArchConfig, p, x, shift_state=None, wkv_state=None):
+def time_mix(cfg: ArchConfig, p, x, wkv, shift_state=None, wkv_state=None):
+    """x: (B, T, d), normed.  ``wkv(r, k, v, lw, u, state)`` is the
+    recurrence's route.  Returns (out, last input, WKV state)."""
     b, t, d = x.shape
     rw = cfg.rwkv
     h = d // rw.head_size
-    xx = _shift(x, shift_state) - x
-    xxx = x + xx * p["mu_x"]
-    mix = jnp.tanh(linear(xxx, p["mix_w1"]))            # (B, T, 5*r)
-    mix = mix.reshape(b, t, _N_MIX, rw.lora_mix)
-    dyn = jnp.einsum("btnr,nrd->btnd", mix, p["mix_w2"])
-    mixed = x[:, :, None, :] + xx[:, :, None, :] * (
-        p["mu_rkvgw"][None, None] + dyn)                # (B, T, 5, d)
-    x_r, x_k, x_v, x_g, x_w = (mixed[:, :, i] for i in range(_N_MIX))
+    with jax.named_scope("qkv"):
+        xx = _shift(x, shift_state) - x
+        xxx = x + xx * p["mu_x"]
+        mix = jnp.tanh(linear(xxx, p["mix_w1"]))            # (B, T, 5*r)
+        mix = mix.reshape(b, t, _N_MIX, rw.lora_mix)
+        dyn = jnp.einsum("btnr,nrd->btnd", mix, p["mix_w2"])
+        mixed = x[:, :, None, :] + xx[:, :, None, :] * (
+            p["mu_rkvgw"][None, None] + dyn)                # (B, T, 5, d)
+        x_r, x_k, x_v, x_g, x_w = (mixed[:, :, i] for i in range(_N_MIX))
+        r = linear(x_r, p["w_r"])
+        k = linear(x_k, p["w_k"])
+        v = linear(x_v, p["w_v"])
+        g = linear(x_g, p["w_g"], activation="silu")
+        w_dyn = linear(jnp.tanh(linear(x_w, p["decay_w1"])), p["decay_w2"],
+                       out_dtype=jnp.float32)
+        lw = -jnp.exp(jnp.clip(p["w0"][None, None].astype(jnp.float32)
+                               + w_dyn, -8.0, 6.0))
 
-    r = linear(x_r, p["w_r"])
-    k = linear(x_k, p["w_k"])
-    v = linear(x_v, p["w_v"])
-    g = linear(x_g, p["w_g"], activation="silu")
-    w_dyn = jnp.tanh(linear(x_w, p["decay_w1"])) @ p["decay_w2"]
-    lw = -jnp.exp(jnp.clip(p["w0"][None, None].astype(jnp.float32)
-                           + w_dyn.astype(jnp.float32), -8.0, 6.0))
-
-    def heads(z):
-        return z.reshape(b, t, h, rw.head_size).transpose(0, 2, 1, 3)
-
-    o = _wkv(cfg, heads(r), heads(k), heads(v), heads(lw), p["u"])
-    o = o.transpose(0, 2, 1, 3).reshape(b, t, d)
-    o = cm.groupnorm_heads(o, p["ln_x"], p["ln_x_b"], h)
-    out = linear(o * g, p["w_o"])
-    return constrain(out, ("batch", "seq", "embed")), x[:, -1]
+    with jax.named_scope("attention"):
+        o, wkv_state = wkv(r, k, v, lw, p["u"], wkv_state)
+    with jax.named_scope("attn_out"):
+        o = cm.groupnorm_heads(o, p["ln_x"], p["ln_x_b"], h,
+                               cfg.rms_eps * HEAD_SIZE_DIVISOR ** 2)
+        out = linear(o * g, p["w_o"])
+    return constrain(out, ("batch", "seq", "embed")), x[:, -1], wkv_state
 
 
+@jax.named_scope("mlp")
 def channel_mix(cfg: ArchConfig, p, x, shift_state=None):
     xx = _shift(x, shift_state) - x
     x_k = x + xx * p["mu_cm_k"]
     x_r = x + xx * p["mu_cm_r"]
-    k = linear(x_k, p["w_cm_k"], activation="relu2")
+    k = linear(x_k, p["w_cm_k"], activation=cfg.mlp_activation)
     kv = linear(k, p["w_cm_v"])
-    return jax.nn.sigmoid(linear(x_r, p["w_cm_r"]).astype(jnp.float32)
+    return jax.nn.sigmoid(linear(x_r, p["w_cm_r"], out_dtype=jnp.float32)
                           ).astype(x.dtype) * kv, x[:, -1]
 
 
-def block_apply(cfg: ArchConfig, p, x):
-    h = cm.layernorm(x, p["ln1"], p["ln1_b"])
-    tm, _ = time_mix(cfg, p, h)
+def block_apply(cfg: ArchConfig, p, x, wkv=None, state=(None, None, None)):
+    """One block over x: (B, T, d).  ``state`` is the layer's
+    (tm_shift, cm_shift, wkv) carried in, or none; returns (x, the
+    states carried out)."""
+    tm_s, cm_s, wkv_s = state
+    h = cm.layernorm(x, p["ln1"], p["ln1_b"], cfg.rms_eps)
+    tm, tm_new, wkv_new = time_mix(cfg, p, h, wkv or _train_wkv(cfg),
+                                   tm_s, wkv_s)
     x = x + tm
-    h = cm.layernorm(x, p["ln2"], p["ln2_b"])
-    cmix, _ = channel_mix(cfg, p, h)
-    return x + cmix
+    h = cm.layernorm(x, p["ln2"], p["ln2_b"], cfg.rms_eps)
+    cmix, cm_new = channel_mix(cfg, p, h, cm_s)
+    return x + cmix, (tm_new, cm_new, wkv_new)
+
+
+def _embed(cfg: ArchConfig, params, tokens):
+    x = cm.embed_tokens(cfg, params["embedding"], tokens)
+    return cm.layernorm(x, params["ln_in"], params["ln_in_b"], cfg.rms_eps)
+
+
+def _final_norm(cfg: ArchConfig, params, x):
+    return cm.layernorm(x, params["ln_final"], params["ln_final_b"],
+                        cfg.rms_eps)
 
 
 def forward(cfg: ArchConfig, params, batch, return_hidden: bool = False):
-    x = cm.embed_tokens(cfg, params["embedding"], batch["tokens"])
-    x = cm.layernorm(x, params["ln_in"], params["ln_in_b"])
+    x = _embed(cfg, params, batch["tokens"])
 
     def body(carry, lp):
-        return block_apply(cfg, lp, carry), None
+        return block_apply(cfg, lp, carry)[0], None
 
     if cfg.remat != "none":
         body = jax.checkpoint(body, policy=cm.remat_policy(cfg),
                               prevent_cse=False)
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    x = cm.layernorm(x, params["ln_final"], params["ln_final_b"])
-    if return_hidden:
-        return x
-    return cm.logits_out(cfg, params, x)
+    x = _final_norm(cfg, params, jax.lax.scan(body, x, params["layers"])[0])
+    return x if return_hidden else cm.logits_out(cfg, params, x)
 
 
 # ---------------------------------------------------------------------------
@@ -232,87 +349,81 @@ def forward(cfg: ArchConfig, params, batch, return_hidden: bool = False):
 def init_cache(cfg: ArchConfig, batch_size: int, max_len: int, dtype=None):
     del max_len                                   # state is O(1) in context
     d, rw = cfg.d_model, cfg.rwkv
-    h = d // rw.head_size
     dt = dtype or cfg.dtype
     n = cfg.n_layers
     return {
         "tm_shift": jnp.zeros((n, batch_size, d), dt),
         "cm_shift": jnp.zeros((n, batch_size, d), dt),
-        "wkv": jnp.zeros((n, batch_size, h, rw.head_size, rw.head_size),
-                         jnp.float32),
+        "wkv": jnp.zeros((n, batch_size, rw.head_size, d), jnp.float32),
     }
 
 
-def _stateful_block(cfg, lp, x, tm_s, cm_s, wkv_s):
-    """Single-step (or chunk) block with explicit state; T small."""
-    b, t, d = x.shape
-    rw = cfg.rwkv
-    h = d // rw.head_size
-    hh = cm.layernorm(x, lp["ln1"], lp["ln1_b"])
-    xx = _shift(hh, tm_s) - hh
-    xxx = hh + xx * lp["mu_x"]
-    mix = jnp.tanh(linear(xxx, lp["mix_w1"])).reshape(
-        b, t, _N_MIX, rw.lora_mix)
-    dyn = jnp.einsum("btnr,nrd->btnd", mix, lp["mix_w2"])
-    mixed = hh[:, :, None, :] + xx[:, :, None, :] * (
-        lp["mu_rkvgw"][None, None] + dyn)
-    x_r, x_k, x_v, x_g, x_w = (mixed[:, :, i] for i in range(_N_MIX))
-    r = linear(x_r, lp["w_r"])
-    k = linear(x_k, lp["w_k"])
-    v = linear(x_v, lp["w_v"])
-    g = linear(x_g, lp["w_g"], activation="silu")
-    w_dyn = jnp.tanh(linear(x_w, lp["decay_w1"])) @ lp["decay_w2"]
-    lw = -jnp.exp(jnp.clip(lp["w0"][None, None].astype(jnp.float32)
-                           + w_dyn.astype(jnp.float32), -8.0, 6.0))
+def _layers(cfg: ArchConfig, params, x, cache, wkv):
+    """Every layer over x: (B, T, d), reading and writing layer i's
+    states in the stacked cache in place (the loop carries the cache)."""
+    def body(carry, lp):
+        x, cache, i = carry
+        state = []
+        for name, scope, _ in _STATES:
+            with jax.named_scope(scope):
+                state.append(jax.lax.dynamic_index_in_dim(
+                    cache[name], i, keepdims=False))
+        x, new = block_apply(cfg, lp, x, wkv, tuple(state))
+        cache = dict(cache)
+        for (name, _, scope), s in zip(_STATES, new):
+            with jax.named_scope(scope):
+                cache[name] = jax.lax.dynamic_update_index_in_dim(
+                    cache[name], s.astype(cache[name].dtype), i, 0)
+        return (x, cache, i + 1), None
 
-    def heads(z):
-        return z.reshape(b, t, h, rw.head_size).transpose(0, 2, 1, 3)
-
-    if t > 1:      # prefill: chunked form (MXU-friendly, compact HLO)
-        o, wkv_new = rwkv6_chunked_jnp(heads(r), heads(k), heads(v),
-                                       heads(lw), lp["u"],
-                                       initial_state=wkv_s)
-    else:          # decode: exact single-step recurrence
-        from repro.kernels.rwkv6.ref import rwkv6_ref
-        o, wkv_new = rwkv6_ref(heads(r), heads(k), heads(v), heads(lw),
-                               lp["u"], initial_state=wkv_s)
-    o = o.transpose(0, 2, 1, 3).reshape(b, t, d)
-    o = cm.groupnorm_heads(o, lp["ln_x"], lp["ln_x_b"], h)
-    x = x + linear(o * g, lp["w_o"])
-    tm_new = hh[:, -1]
-
-    hh = cm.layernorm(x, lp["ln2"], lp["ln2_b"])
-    cmix, cm_new = channel_mix(cfg, lp, hh, cm_s)
-    return x + cmix, tm_new, cm_new, wkv_new
+    (x, cache, _), _ = jax.lax.scan(body, (x, cache, jnp.int32(0)),
+                                    params["layers"])
+    return x, cache
 
 
-def _run_stateful(cfg, params, tokens, cache):
-    x = cm.embed_tokens(cfg, params["embedding"], tokens)
-    x = cm.layernorm(x, params["ln_in"], params["ln_in_b"])
-
-    def body(carry, layer):
-        x = carry
-        lp, tm_s, cm_s, wkv_s = layer
-        x, tm, cms, wkv = _stateful_block(cfg, lp, x, tm_s, cm_s, wkv_s)
-        return x, (tm, cms, wkv)
-
-    x, (tm, cms, wkv) = jax.lax.scan(
-        body, x, (params["layers"], cache["tm_shift"], cache["cm_shift"],
-                  cache["wkv"]))
-    new_cache = {"tm_shift": tm.astype(cache["tm_shift"].dtype),
-                 "cm_shift": cms.astype(cache["cm_shift"].dtype),
-                 "wkv": wkv}
-    x = cm.layernorm(x, params["ln_final"], params["ln_final_b"])
-    return cm.logits_out(cfg, params, x[:, -1]), new_cache
+def _count(route: str, step: str, n: int):
+    from repro.obs import default_registry
+    default_registry().counter("rwkv_wkv_calls_total", route=route,
+                               step=step).inc(n)
 
 
 def prefill(cfg: ArchConfig, params, batch, cache):
-    return _run_stateful(cfg, params, batch["tokens"], cache)
+    """The prompt in slices of ``PREFILL_SLICE`` tokens (a
+    shorter remainder first), each through every layer, the states
+    carried from slice to slice: activations stay those of one slice."""
+    tokens = batch["tokens"]
+    b, s = tokens.shape
+    size = PREFILL_SLICE
+    n, rem = divmod(s, size)
+    _count("chunked", "prefill", n + (rem > 0))
+
+    def run(toks, cache):
+        x, cache = _layers(cfg, params, _embed(cfg, params, toks), cache,
+                           functools.partial(_chunked,
+                                             chunk=PREFILL_WKV_CHUNK))
+        return x[:, -1], cache
+
+    if rem:
+        last, cache = run(tokens[:, :rem], cache)
+    if n:
+        slices = tokens[:, rem:].reshape(b, n, size).swapaxes(0, 1)
+
+        def body(cache, toks):
+            last, cache = run(toks, cache)
+            return cache, last
+
+        cache, lasts = jax.lax.scan(body, cache, slices)
+        last = lasts[-1]
+    return cm.logits_out(cfg, params, _final_norm(cfg, params, last)), cache
 
 
 def decode_step(cfg: ArchConfig, params, tokens, cache, pos):
     del pos                                        # state carries position
-    return _run_stateful(cfg, params, tokens, cache)
+    _count("recurrent", "decode", 1)
+    x, cache = _layers(cfg, params, _embed(cfg, params, tokens), cache,
+                       _recurrent)
+    return cm.logits_out(cfg, params, _final_norm(cfg, params, x[:, -1])), \
+        cache
 
 
 register_family("rwkv6")(sys.modules[__name__])

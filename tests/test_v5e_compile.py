@@ -6,7 +6,9 @@ host, for a v5e that is described and not attached, so Mosaic refusals
 runs: these say nothing about results or speed.  Widths are yi-6b's
 (d_model 4096, d_ff 11008, 32 query / 4 KV heads of 128) and, for the
 kernels yi-6b does not use, those of the configs that do (olmoe-1b-7b
-experts, recurrentgemma-2b RG-LRU, rwkv6-7b heads).
+experts, recurrentgemma-2b RG-LRU, rwkv6-7b heads).  One more compiles
+rwkv6-7b's serving steps, a prefill slice and a decode step, at
+published widths and the chip cell's batch.
 
 The topology is described inside a fixture only: the TPU library may be
 loaded by one process at a time, so it is never touched while modules
@@ -135,3 +137,32 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for shape, dtype in arg_specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_rwkv6_prefill_slice_and_decode_step_compile_for_v5e(one_chip):
+    """rwkv6-7b at published widths, cut to 2 layers: one prefill slice
+    and one decode step of the serving cell's batch of 128 compile for a
+    v5e, the recurrent state updated in place (it is donated)."""
+    from repro.configs.registry import get_config
+    from repro.models import rwkv6
+    cfg = get_config("rwkv6-7b").with_(n_layers=2)
+    b = 128
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda k: rwkv6.init(cfg, k),
+                                    jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: rwkv6.init_cache(cfg, b, 0)))
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    steps = {
+        "prefill": (lambda p, t, c: rwkv6.prefill(cfg, p, {"tokens": t}, c),
+                    rwkv6.PREFILL_SLICE),
+        "decode": (lambda p, t, c: rwkv6.decode_step(cfg, p, t, c, 0), 1)}
+    for name, (fn, t) in steps.items():
+        tokens = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+        m = jax.jit(fn, donate_argnums=2).lower(
+            params, tokens, cache).compile().memory_analysis()
+        assert m.alias_size_in_bytes >= state_bytes, name
