@@ -23,16 +23,20 @@ kernel for ``pallas``, the per-token oracle for ``dense``, else
 ``lax.scan``, so cost_analysis sees its FLOPs).  Serving does not read
 ``cfg.backend``: prefill streams the prompt through the state in slices
 of ``PREFILL_SLICE`` tokens through the chunked form, and
-decode takes the exact per-token step.
+decode takes the token through ``kernels/rwkv6``'s step kernel
+(``rwkv6_decode_step``), which reads a layer's WKV state once and
+writes it in place in the stacked cache.  The per-token oracle
+``_recurrent`` serves the ``dense`` backend and the kernel's tests.
 
 Layer scopes, for the device trace: ``embed``, ``head``; ``norm`` (the
 LayerNorms); ``qkv`` (token shift, DDLerp, the r/k/v/g projections and
-the decay LoRA); ``attention`` (the WKV recurrence); ``attn_out``
-(GroupNorm, gate, ``w_o``); ``mlp`` (the channel mix); ``cache_update``
-(writing the shift states back; the WKV state's write is the
-recurrence's, in ``attention``).  Each traced serving call adds to
-``rwkv_wkv_calls_total{route, step}``: the prefill's slices, or one
-decode step.
+the decay LoRA); ``attention`` (the WKV recurrence with its state's read
+and write: prefill's slice and in-place update, decode's kernel call);
+``attn_out`` (GroupNorm, gate, ``w_o``); ``mlp`` (the channel mix);
+``cache_update`` (writing the shift states back).  Each traced serving
+call adds to ``rwkv_wkv_calls_total{route, step}``: the prefill's
+slices, or one decode step; the decode kernel's route adds one to
+``rwkv_wkv_inplace_steps_total`` each time it is traced.
 """
 
 from __future__ import annotations
@@ -49,13 +53,12 @@ from repro.models import common as cm
 from repro.models.base import ArchConfig, register_family
 
 _N_MIX = 5     # r, k, v, g, w
-#: a layer's states in the serving cache, with the layer scopes that
-#: read and write them.  The WKV state is written back in ``attention``:
-#: the compiler fuses the recurrence's update into that in-place write,
-#: which would otherwise take the recurrence out of its scope.
+#: a layer's token-shift states in the serving cache, with the layer
+#: scopes that read and write them.  The WKV state is not among them:
+#: the serving WKV routes take the whole stack and the layer's index, so
+#: its read and write lie in ``attention`` (``_sliced``, ``_in_place``).
 _STATES = (("tm_shift", "qkv", "cache_update"),
-           ("cm_shift", "mlp", "cache_update"),
-           ("wkv", "attention", "attention"))
+           ("cm_shift", "mlp", "cache_update"))
 HEAD_SIZE_DIVISOR = 8          # the published GroupNorm eps is eps * 8 ** 2
 #: serving prefill's tokens per slice: the longest whose program fits a
 #: v5e at the chip cell's batch of 128 (memory_analysis, PERF.md)
@@ -178,6 +181,33 @@ def _recurrent(r, k, v, lw, u, state):
     state, o = jax.lax.scan(step, state, tuple(
         jnp.moveaxis(z, 1, 0) for z in (r, k, v, lw)))
     return jnp.moveaxis(o, 0, 1).astype(r.dtype), state
+
+
+# A serving WKV route takes, in place of the state, the stacked states of
+# every layer and this layer's index (stack, i), and gives back the
+# stack with layer i advanced.
+
+def _sliced(route):
+    """``route`` on layer i's state, sliced out of the stack and written
+    back in place (the compiler fuses the update into that write)."""
+    def run(r, k, v, lw, u, state):
+        stack, i = state
+        o, s = route(r, k, v, lw, u,
+                     jax.lax.dynamic_index_in_dim(stack, i, keepdims=False))
+        return o, jax.lax.dynamic_update_index_in_dim(stack, s, i, 0)
+    return run
+
+
+def _in_place(r, k, v, lw, u, state):
+    """One token through the decode kernel, which reads layer i's state
+    once and writes it in place in the stack.  The layer scan traces
+    this once per decode step, which counts it once."""
+    from repro.kernels.rwkv6.ops import rwkv6_decode_step
+    _count("rwkv_wkv_inplace_steps_total", 1)
+    stack, i = state
+    o, stack = rwkv6_decode_step(stack, i, r[:, 0], k[:, 0], v[:, 0],
+                                 lw[:, 0], u)
+    return o[:, None], stack
 
 
 def _train_wkv(cfg: ArchConfig):
@@ -307,8 +337,8 @@ def channel_mix(cfg: ArchConfig, p, x, shift_state=None):
 
 def block_apply(cfg: ArchConfig, p, x, wkv=None, state=(None, None, None)):
     """One block over x: (B, T, d).  ``state`` is the layer's
-    (tm_shift, cm_shift, wkv) carried in, or none; returns (x, the
-    states carried out)."""
+    (tm_shift, cm_shift, wkv) carried in, or none, the WKV state in the
+    form ``wkv`` takes; returns (x, the states carried out)."""
     tm_s, cm_s, wkv_s = state
     h = cm.layernorm(x, p["ln1"], p["ln1_b"], cfg.rms_eps)
     tm, tm_new, wkv_new = time_mix(cfg, p, h, wkv or _train_wkv(cfg),
@@ -360,7 +390,8 @@ def init_cache(cfg: ArchConfig, batch_size: int, max_len: int, dtype=None):
 
 def _layers(cfg: ArchConfig, params, x, cache, wkv):
     """Every layer over x: (B, T, d), reading and writing layer i's
-    states in the stacked cache in place (the loop carries the cache)."""
+    states in the stacked cache in place (the loop carries the cache);
+    ``wkv`` is a serving route, on the stacked WKV state."""
     def body(carry, lp):
         x, cache, i = carry
         state = []
@@ -368,8 +399,9 @@ def _layers(cfg: ArchConfig, params, x, cache, wkv):
             with jax.named_scope(scope):
                 state.append(jax.lax.dynamic_index_in_dim(
                     cache[name], i, keepdims=False))
-        x, new = block_apply(cfg, lp, x, wkv, tuple(state))
-        cache = dict(cache)
+        x, new = block_apply(cfg, lp, x, wkv,
+                             (*state, (cache["wkv"], i)))
+        cache = dict(cache, wkv=new[-1])
         for (name, _, scope), s in zip(_STATES, new):
             with jax.named_scope(scope):
                 cache[name] = jax.lax.dynamic_update_index_in_dim(
@@ -381,10 +413,9 @@ def _layers(cfg: ArchConfig, params, x, cache, wkv):
     return x, cache
 
 
-def _count(route: str, step: str, n: int):
+def _count(name: str, n: int, **labels):
     from repro.obs import default_registry
-    default_registry().counter("rwkv_wkv_calls_total", route=route,
-                               step=step).inc(n)
+    default_registry().counter(name, **labels).inc(n)
 
 
 def prefill(cfg: ArchConfig, params, batch, cache):
@@ -395,12 +426,13 @@ def prefill(cfg: ArchConfig, params, batch, cache):
     b, s = tokens.shape
     size = PREFILL_SLICE
     n, rem = divmod(s, size)
-    _count("chunked", "prefill", n + (rem > 0))
+    _count("rwkv_wkv_calls_total", n + (rem > 0), route="chunked",
+           step="prefill")
 
     def run(toks, cache):
         x, cache = _layers(cfg, params, _embed(cfg, params, toks), cache,
-                           functools.partial(_chunked,
-                                             chunk=PREFILL_WKV_CHUNK))
+                           _sliced(functools.partial(
+                               _chunked, chunk=PREFILL_WKV_CHUNK)))
         return x[:, -1], cache
 
     if rem:
@@ -419,9 +451,9 @@ def prefill(cfg: ArchConfig, params, batch, cache):
 
 def decode_step(cfg: ArchConfig, params, tokens, cache, pos):
     del pos                                        # state carries position
-    _count("recurrent", "decode", 1)
+    _count("rwkv_wkv_calls_total", 1, route="recurrent", step="decode")
     x, cache = _layers(cfg, params, _embed(cfg, params, tokens), cache,
-                       _recurrent)
+                       _in_place)
     return cm.logits_out(cfg, params, _final_norm(cfg, params, x[:, -1])), \
         cache
 

@@ -226,6 +226,33 @@ def test_generate_on_stateful_family():
                                       np.asarray(expect))
 
 
+def test_every_rwkv_decode_step_takes_the_in_place_kernel():
+    """A traced serving call counts its decode step once under the
+    recurrent route and once as an in-place kernel step: engagement 1."""
+    from repro.obs import default_registry
+    cfg = _cfg("rwkv6-7b")
+    params = jax.eval_shape(lambda k: family_module(cfg).init(cfg, k),
+                            jax.random.PRNGKey(0))
+    reg = default_registry()
+    was = reg.enabled
+    reg.enable()
+    try:
+        reg.clear()
+        jax.clear_caches()
+        lower_generate(cfg, params,
+                       {"tokens": jax.ShapeDtypeStruct((2, 8), jnp.int32)},
+                       max_new_tokens=4, cache_len=12)
+        c = reg.snapshot()["counters"]
+        decode = [x["value"] for x in c["rwkv_wkv_calls_total"]
+                  if x["labels"] == {"route": "recurrent", "step": "decode"}]
+        assert decode == [1]
+        assert [x["value"] for x in c["rwkv_wkv_inplace_steps_total"]] == [1]
+    finally:
+        reg.clear()
+        if not was:
+            reg.disable()
+
+
 def _serve(argv, monkeypatch):
     from repro.launch import serve
     monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)

@@ -6,9 +6,9 @@ host, for a v5e that is described and not attached, so Mosaic refusals
 runs: these say nothing about results or speed.  Widths are yi-6b's
 (d_model 4096, d_ff 11008, 32 query / 4 KV heads of 128) and, for the
 kernels yi-6b does not use, those of the configs that do (olmoe-1b-7b
-experts, recurrentgemma-2b RG-LRU, rwkv6-7b heads).  One more compiles
-rwkv6-7b's serving steps, a prefill slice and a decode step, at
-published widths and the chip cell's batch.
+experts, recurrentgemma-2b RG-LRU, rwkv6-7b heads and its serving
+decode state).  One more compiles rwkv6-7b's serving steps, a prefill
+slice and a decode step, at published widths and the chip cell's batch.
 
 The topology is described inside a fixture only: the TPU library may be
 loaded by one process at a time, so it is never touched while modules
@@ -16,6 +16,7 @@ are imported, and only the worker that runs this file loads it.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,9 +30,9 @@ from repro.kernels.matmul.ops import fused_matmul
 from repro.kernels.moe.ops import grouped_matmul
 from repro.kernels.quant.ops import quantize_rowwise
 from repro.kernels.rglru.ops import rglru_scan
-from repro.kernels.rwkv6.ops import rwkv6_scan
+from repro.kernels.rwkv6.ops import rwkv6_decode_step, rwkv6_scan
 
-BF, I8, F32 = jnp.bfloat16, jnp.int8, jnp.float32
+BF, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
 TOKENS = 2048                      # 4 prompts of 512 tokens
 
 
@@ -127,6 +128,13 @@ CASES = {
         lambda r, k, v, lw, u: rwkv6_scan(r, k, v, lw, u, chunk=32,
                                           interpret=False),
         [((1, 64, 2048, 64), BF)] * 4 + [((64, 64), F32)]),
+    # rwkv6-16l.decode's state: 16 layers, batch 128, 64 heads of 64;
+    # and a batch that 8 does not divide (a serving chunk's leftover).
+    **{f"rwkv6_decode_step_16x{b}x64x4096": (
+        lambda stack, layer, r, k, v, lw, u: rwkv6_decode_step(
+            stack, layer, r, k, v, lw, u, interpret=False),
+        [((16, b, 64, 4096), F32), ((), I32)] + [((b, 4096), BF)] * 3
+        + [((b, 4096), F32), ((64, 64), F32)]) for b in (128, 100)},
 }
 
 
@@ -139,12 +147,26 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_rwkv6_prefill_slice_and_decode_step_compile_for_v5e(one_chip):
+#: a copy or broadcast of the 2-layer stacked WKV state at batch 128, or
+#: of a per-key operand of one layer's state size
+STATE_SIZED = re.compile(r"= f32\[(2,128,64,4096|128,64,64,64)\]\S* "
+                         r"(copy|broadcast)\(")
+
+
+def test_rwkv6_prefill_slice_and_decode_step_compile_for_v5e(one_chip,
+                                                            monkeypatch):
     """rwkv6-7b at published widths, cut to 2 layers: one prefill slice
     and one decode step of the serving cell's batch of 128 compile for a
-    v5e, the recurrent state updated in place (it is donated)."""
+    v5e, the recurrent state updated in place (it is donated).  Decode
+    runs the WKV state through the step kernel: no copy or broadcast of
+    the stacked state, or of a per-key state-sized operand, is left."""
+    import repro.kernels.rwkv6.ops as wkv_ops
     from repro.configs.registry import get_config
     from repro.models import rwkv6
+    # The host's backend is the CPU, where kernels would be interpreted:
+    # compile them with Mosaic, for the described chip.
+    monkeypatch.setattr(wkv_ops, "resolve_interpret", lambda _=None: False)
+    jax.clear_caches()
     cfg = get_config("rwkv6-7b").with_(n_layers=2)
     b = 128
 
@@ -163,6 +185,12 @@ def test_rwkv6_prefill_slice_and_decode_step_compile_for_v5e(one_chip):
         "decode": (lambda p, t, c: rwkv6.decode_step(cfg, p, t, c, 0), 1)}
     for name, (fn, t) in steps.items():
         tokens = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
-        m = jax.jit(fn, donate_argnums=2).lower(
-            params, tokens, cache).compile().memory_analysis()
+        compiled = jax.jit(fn, donate_argnums=2).lower(
+            params, tokens, cache).compile()
+        m = compiled.memory_analysis()
         assert m.alias_size_in_bytes >= state_bytes, name
+        if name == "decode":
+            text = compiled.as_text()
+            assert "tpu_custom_call" in text
+            assert not STATE_SIZED.findall(text)
+    jax.clear_caches()
