@@ -28,10 +28,11 @@ granularity (``tile | panel | layer``), epilogue fusion and a cluster
 Every front door goes through the registry: ``serving.ServingEngine``
 lowers batch schedules here (``plan(units=N)`` prices them on contended
 cluster timelines), ``benchmarks/run.py --engine``/``--units`` is a
-registry lookup, the model zoo's ``linear`` resolves its matmul route
-here, and ``examples/sim_timeline.py`` / ``examples/cluster_scaling.py``
-drive several backends with one graph.  A new engine is one
-``@register`` away.
+registry lookup, ``set_default_matmul_backend`` sets the model zoo's
+matmul route (held by ``core.fusion``, which imports nothing from this
+package), and ``examples/sim_timeline.py`` /
+``examples/cluster_scaling.py`` drive several backends with one graph.
+A new engine is one ``@register`` away.
 
 Typical use::
 
@@ -46,10 +47,9 @@ Typical use::
 
 from repro.backend.base import (Backend, DispatchHandle, ExecResult,
                                 MatMulOperands, NO_MATMUL_OPERANDS)
-from repro.backend.registry import (ALIASES, available,
-                                    default_matmul_backend, dispatch_platform,
+from repro.backend.registry import (ALIASES, available, dispatch_platform,
                                     get, get_tuned, matmul_backend_string,
-                                    register, resolve, routing_key,
+                                    register, resolve,
                                     set_default_matmul_backend,
                                     set_dispatch_platform, set_tuned_dispatch,
                                     tuned_config, tuned_dispatch_enabled)
@@ -64,9 +64,8 @@ from repro.backend.sharded_backend import ShardedBackend
 __all__ = [
     "Backend", "DispatchHandle", "ExecResult", "MatMulOperands",
     "NO_MATMUL_OPERANDS",
-    "ALIASES", "available", "default_matmul_backend", "dispatch_platform",
+    "ALIASES", "available", "dispatch_platform",
     "get", "get_tuned", "matmul_backend_string", "register", "resolve",
-    "routing_key",
     "set_default_matmul_backend", "set_dispatch_platform",
     "set_tuned_dispatch", "tuned_config", "tuned_dispatch_enabled",
     "JaxBackend", "PallasBackend", "DESimBackend", "AnalyticalBackend",

@@ -1,5 +1,5 @@
-"""Backend registry: names -> Backend classes, the zoo's default matmul
-route, and the tuned capability-dispatch layer.
+"""Backend registry: names -> Backend classes, the setter of the zoo's
+matmul route, and the tuned capability-dispatch layer.
 
 ``get("desim", unit=..., granularity="panel")`` is the one lookup every
 front door (serving, launch, benchmarks, examples, tests) goes through;
@@ -9,9 +9,11 @@ registering a new engine (multi-core DES, sharded execution, ...) is a
 ``get_tuned`` is the capability-aware variant: it resolves the best
 autotuned kernel configuration for (current platform × shape class)
 from the :mod:`repro.tune` cache and folds it into the constructor
-kwargs.  Dispatch precedence, everywhere: **explicit argument > tuned
-cache > untuned default** — passing any kwarg explicitly always wins,
-and a missing/invalid cache silently degrades to the untuned defaults.
+kwargs.  Precedence: **explicit argument > tuned cache > untuned
+default** — passing any kwarg explicitly always wins, and a
+missing/invalid cache silently degrades to the untuned defaults.  The
+tuned layer prices simulated schedules only: the model zoo's matmul
+route is ``core.fusion.default_route()``, which reads no tuning cache.
 """
 
 from __future__ import annotations
@@ -19,6 +21,7 @@ from __future__ import annotations
 from typing import Callable, Optional, Type
 
 from repro.backend.base import Backend
+from repro.core import fusion
 
 _REGISTRY: "dict[str, Type[Backend]]" = {}
 
@@ -67,55 +70,29 @@ def available() -> "tuple[str, ...]":
 
 
 # ---------------------------------------------------------------------------
-# The model zoo's matmul route.  ``core.fusion.linear`` calls are resolved
-# through here so the zoo speaks registry vocabulary; the default stays on
-# the eager jax backend because Pallas-everywhere is too slow under
-# interpret mode on CPU for whole-model tests (per-kernel coverage lives
-# in tests/).
+# The model zoo's matmul route lives in ``core.fusion``; this is its one
+# setter, so the route speaks registry vocabulary.
 # ---------------------------------------------------------------------------
-
-_DEFAULT_MATMUL = "jax"
-
 
 def set_default_matmul_backend(name: str) -> str:
     """Route the model zoo's ``linear``/``cute_matmul`` calls through a
-    different executing backend.  Returns the previous setting."""
-    global _DEFAULT_MATMUL, _MATMUL_SET_EXPLICITLY
+    different executing backend.  Returns the previous route, a name
+    this function accepts back."""
     canon = resolve(name)
     cls = _REGISTRY[canon]
-    if not cls.executes or cls.models_time:
+    route = getattr(cls, "matmul_string", None)
+    if route is None or not cls.executes or cls.models_time:
         raise ValueError(
             f"backend {canon!r} is not an eager matmul route for the "
             "model zoo; use 'jax' or 'pallas' (modelling backends price "
             "schedules, they don't serve projections)")
-    prev, _DEFAULT_MATMUL = _DEFAULT_MATMUL, canon
-    _MATMUL_SET_EXPLICITLY = True
+    prev, fusion._default_route = fusion._default_route, route
     return prev
 
 
-def default_matmul_backend() -> str:
-    return _DEFAULT_MATMUL
-
-
-def matmul_backend_string(name: Optional[str] = None,
-                          shape: "Optional[tuple]" = None) -> str:
-    """The ``cute_matmul(backend=...)`` string for a registry name.
-
-    ``name=None`` resolves the default route with tuned-dispatch
-    precedence: an explicit ``set_default_matmul_backend`` setting wins;
-    otherwise, when ``shape`` (``(m, n, k)``) is given and the current
-    platform's tuning cache pins a route for that shape class, the tuned
-    route is used; else the untuned default (``"jax"`` → ``"xla"``).
-    """
-    if name is None and shape is not None and not _MATMUL_SET_EXPLICITLY:
-        cfg = tuned_config(shape=shape)
-        if cfg is not None and cfg.route is not None:
-            return cfg.route
-    cls = _REGISTRY[resolve(name or _DEFAULT_MATMUL)]
-    s = getattr(cls, "matmul_string", None)
-    if s is None:
-        raise ValueError(f"backend {cls.name!r} has no cute_matmul route")
-    return s
+def matmul_backend_string() -> str:
+    """The ``cute_matmul(backend=...)`` string the zoo's calls take."""
+    return fusion.default_route()
 
 
 # ---------------------------------------------------------------------------
@@ -124,7 +101,6 @@ def matmul_backend_string(name: Optional[str] = None,
 
 _DISPATCH_PLATFORM = "shuttle"       # the repo's canonical platform
 _TUNED_DISPATCH = True
-_MATMUL_SET_EXPLICITLY = False
 
 
 def set_dispatch_platform(platform) -> str:
@@ -151,17 +127,6 @@ def set_tuned_dispatch(enabled: bool) -> bool:
 
 def tuned_dispatch_enabled() -> bool:
     return _TUNED_DISPATCH
-
-
-def routing_key() -> tuple:
-    """Everything ``matmul_backend_string`` reads besides its arguments:
-    the default route, whether it was set explicitly, the dispatch
-    platform, the tuned-dispatch switch and the tuning caches' generation.
-    A jitted program whose trace resolves routes takes this as a static
-    argument, so changing any of them compiles a new program."""
-    from repro.tune.cache import generation
-    return (_DEFAULT_MATMUL, _MATMUL_SET_EXPLICITLY, _DISPATCH_PLATFORM,
-            _TUNED_DISPATCH, generation())
 
 
 def _platform_name(platform) -> str:

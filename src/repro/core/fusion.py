@@ -17,6 +17,11 @@ Backends
   in ``core.constraint``.
 * ``"auto"``  — pallas when the shapes meet the kernel's divisibility
   contract on a real TPU, else xla.  On CPU hosts auto → xla.
+
+A call that names no backend takes the process-wide route,
+:func:`default_route` (``"xla"``), which depends on nothing else: no
+shape, platform or tuning cache.  Its one setter is
+``repro.backend.set_default_matmul_backend``.
 """
 
 from __future__ import annotations
@@ -145,6 +150,21 @@ def apply_epilogue(acc: jax.Array, ep: Epilogue, ops: EpilogueOperands,
 # The unified entry point.
 # ---------------------------------------------------------------------------
 
+#: the route ``cute_matmul`` takes when the call names none.  The
+#: default stays on XLA because the Pallas matmul everywhere is too slow
+#: under interpret mode on CPU for whole-model tests.  Written only by
+#: ``repro.backend.set_default_matmul_backend``, which checks the name
+#: against the backend registry first.
+_default_route = "xla"
+
+
+def default_route() -> str:
+    """The process-wide ``cute_matmul`` route (``"xla"`` or
+    ``"pallas"``).  A jitted program that traces route-less calls keys
+    on it, so a new route compiles a new program."""
+    return _default_route
+
+
 def _infer_policy(a: jax.Array) -> PrecisionPolicy:
     table = {
         jnp.int8.dtype: prec.INT8,
@@ -166,20 +186,14 @@ def cute_matmul(a: jax.Array, b: jax.Array, *,
     """C = epilogue(A @ B).  A: (..., M, K), B: (K, N) (or (..., K, N)).
 
     ``backend`` is a ``cute_matmul`` route string (``"xla"``,
-    ``"pallas"``, ``"auto"``); ``None`` resolves the process-wide default
-    from the ``repro.backend`` registry with tuned-dispatch precedence:
-    ``set_default_matmul_backend`` wins, else a route the current
-    platform's tuning cache pins for this shape class, else ``"xla"``.
+    ``"pallas"``, ``"auto"``); ``None`` takes :func:`default_route`.
 
     ``epilogue.transpose`` equivalent: the paper's result-transpose flag is
     expressed by the caller transposing the (cheap, fused) output — XLA
     folds it into the consuming op's layout.
     """
     if backend is None:
-        from repro.backend import matmul_backend_string   # lazy: no cycle
-        m = a.shape[-2] if a.ndim >= 2 else 1
-        backend = matmul_backend_string(
-            shape=(m, b.shape[-1], a.shape[-1]))
+        backend = _default_route
     if policy is None:
         policy = _infer_policy(a)
     if backend == "auto":

@@ -1,7 +1,8 @@
 """Shared layers for the model zoo.
 
 Every matmul in this file goes through ``core.fusion`` (``cute_matmul`` /
-``linear``) so the paper's fused-epilogue contract applies framework-wide.
+``linear``) so the paper's fused-epilogue contract applies framework-wide;
+it takes fusion's process-wide route, as every family's matmuls do.
 Attention offers three implementations:
 
 * ``xla``    — chunked online-softmax in pure jnp (lax.scan over KV
@@ -264,9 +265,9 @@ def attn_init(cfg: ArchConfig, key, *, d_in: Optional[int] = None):
 def qkv_project(cfg: ArchConfig, p, x, positions):
     """x: (B, S, d) -> q (B, H, S, hd), k/v (B, Hkv, S, hd) with RoPE."""
     b, s, _ = x.shape
-    q = linear(x, p["wq"], p.get("bq"), backend=_mm_backend(cfg))
-    k = linear(x, p["wk"], p.get("bk"), backend=_mm_backend(cfg))
-    v = linear(x, p["wv"], p.get("bv"), backend=_mm_backend(cfg))
+    q = linear(x, p["wq"], p.get("bq"))
+    k = linear(x, p["wk"], p.get("bk"))
+    v = linear(x, p["wv"], p.get("bv"))
     q = q.reshape(b, s, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
@@ -287,18 +288,7 @@ def attn_out(cfg: ArchConfig, p, ctx):
     """ctx: (B, H, S, hd) -> (B, S, d)."""
     b, h, s, hd = ctx.shape
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-    return linear(ctx, p["wo"], backend=_mm_backend(cfg))
-
-
-def _mm_backend(cfg: ArchConfig) -> str:
-    # The zoo's matmul route is a registry lookup: repro.backend's
-    # set_default_matmul_backend re-routes every projection here.  The
-    # default stays on eager XLA because Pallas matmul everywhere is too
-    # slow under interpret mode on CPU for whole-model tests; per-kernel
-    # coverage lives in tests/.  cfg.backend routes *attention* through
-    # the flash kernel.
-    from repro.backend import matmul_backend_string
-    return matmul_backend_string()
+    return linear(ctx, p["wo"])
 
 
 # ---------------------------------------------------------------------------
@@ -317,10 +307,9 @@ def mlp_init(cfg: ArchConfig, key, d_ff: Optional[int] = None):
 
 @jax.named_scope("mlp")
 def mlp_apply(cfg: ArchConfig, p, x):
-    h = linear(x, p["wi"], activation=cfg.mlp_activation, glu=cfg.mlp_glu,
-               backend=_mm_backend(cfg))
+    h = linear(x, p["wi"], activation=cfg.mlp_activation, glu=cfg.mlp_glu)
     h = constrain(h, ("batch", "seq", "mlp"))
-    return linear(h, p["wo"], backend=_mm_backend(cfg))
+    return linear(h, p["wo"])
 
 
 # ---------------------------------------------------------------------------
@@ -339,8 +328,7 @@ def embed_tokens(cfg: ArchConfig, embedding, tokens):
 def logits_out(cfg: ArchConfig, params, x):
     w = (params["embedding"].T if cfg.tie_embeddings
          else params["lm_head"])
-    y = linear(x, w, softcap=cfg.final_softcap, out_dtype=jnp.float32,
-               backend=_mm_backend(cfg))
+    y = linear(x, w, softcap=cfg.final_softcap, out_dtype=jnp.float32)
     return constrain(y, ("batch", "seq", "vocab") if y.ndim == 3
                      else ("batch", "vocab"))
 

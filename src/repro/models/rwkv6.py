@@ -17,15 +17,15 @@ Paper applicability (DESIGN.md §4): the recurrence is vector work — all
 projections still flow through ``cute_matmul``; the chunked WKV turns
 the state update into MXU-sized outer products.
 
-Training's ``forward`` picks the WKV by ``cfg.backend``: the Pallas
-kernel for ``pallas``, the per-token oracle for ``dense``, else
-``rwkv6_chunked_jnp`` (the kernel's chunked math in jnp under
-``lax.scan``, so cost_analysis sees its FLOPs).  Serving does not read
-``cfg.backend``: prefill streams the prompt through the state in slices
-of ``PREFILL_SLICE`` tokens through the chunked form, and
-decode takes the token through ``kernels/rwkv6``'s step kernel
-(``rwkv6_decode_step``), which reads a layer's WKV state once and
-writes it in place in the stacked cache.  The per-token oracle
+Training's ``forward`` picks the WKV by ``cfg.backend``: the per-token
+oracle for ``dense``, else ``rwkv6_chunked_jnp`` (the chunked math in
+jnp under ``lax.scan``, so cost_analysis sees its FLOPs; on the chip it
+beat a Pallas scan of the same math at every chunk size, PERF.md).
+Serving does not read ``cfg.backend``: prefill streams the prompt
+through the state in slices of ``PREFILL_SLICE`` tokens through the
+chunked form, and decode takes the token through ``kernels/rwkv6``'s
+step kernel (``rwkv6_decode_step``), which reads a layer's WKV state
+once and writes it in place in the stacked cache.  The per-token oracle
 ``_recurrent`` serves the ``dense`` backend and the kernel's tests.
 
 Layer scopes, for the device trace: ``embed``, ``head``; ``norm`` (the
@@ -68,7 +68,7 @@ PREFILL_WKV_CHUNK = 16
 
 
 # ---------------------------------------------------------------------------
-# Chunked WKV in pure jnp (shared math with the Pallas kernel).
+# Chunked WKV in pure jnp.
 # ---------------------------------------------------------------------------
 
 def rwkv6_chunked_jnp(r, k, v, lw, u, *, chunk: int = 64,
@@ -212,14 +212,6 @@ def _in_place(r, k, v, lw, u, state):
 
 def _train_wkv(cfg: ArchConfig):
     """Training's WKV route, by ``cfg.backend``; starts from no state."""
-    if cfg.backend == "pallas":
-        from repro.kernels.rwkv6.ops import rwkv6_scan
-
-        def pallas(r, k, v, lw, u, _):
-            h = u.shape[0]
-            return _unheads(rwkv6_scan(*(_heads(z, h) for z in (r, k, v, lw)),
-                                       u, chunk=32)), None
-        return pallas
     if cfg.backend == "dense":
         return _recurrent
     return _chunked

@@ -2,10 +2,10 @@
 
 The paper's asyncMatMul/checkMatmul contract shows up twice here:
 
-* per step — every projection is a ``cute_matmul`` with fused epilogue,
-  routed through the ``repro.backend`` registry default
-  (``set_default_matmul_backend`` re-routes serving without touching
-  this module);
+* per step — every projection is a ``cute_matmul`` with fused epilogue
+  on ``core.fusion``'s process-wide route
+  (``repro.backend.set_default_matmul_backend`` re-routes serving
+  without touching this module);
 * across *schedules* — ``ServingEngine.plan`` lowers the pending queue
   into a continuous-batching prefill/decode :class:`BatchSchedule` whose
   ``LayerTrace`` steps feed ``sim.lower.workload_to_graph``, so a
@@ -28,7 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.backend.registry import routing_key
+from repro.core.fusion import default_route
 from repro.core.precision import DataType
 from repro.core.simulator import VECTOR_OP_INSTRS, LayerTrace
 from repro.core.task import MatMulTask
@@ -100,16 +100,17 @@ def generate(cfg: ArchConfig, params, batch, *, max_new_tokens: int,
     """
     return _generate(cfg, params, batch, max_new_tokens=max_new_tokens,
                      temperature=temperature, key=key, cache_len=cache_len,
-                     keep_logits=keep_logits, route=routing_key())
+                     keep_logits=keep_logits, route=default_route())
 
 
 def lower_generate(cfg: ArchConfig, params, batch, **kw):
     """``generate``'s program for these arguments, lowered (not run)."""
-    return _generate.lower(cfg, params, batch, route=routing_key(), **kw)
+    return _generate.lower(cfg, params, batch, route=default_route(),
+                           **kw)
 
 
 # ``route`` is never read: the matmul route is process-wide state that
-# tracing reads (``repro.backend.routing_key``), so it is part of the key
+# tracing reads (``core.fusion.default_route``), so it is part of the key
 # or a program traced under one route would be reused under another.
 @functools.partial(jax.jit, static_argnames=(
     "cfg", "max_new_tokens", "temperature", "cache_len", "keep_logits",

@@ -85,7 +85,6 @@ def load_cache(platform_name: str,
 
 
 _MEMO: "dict[tuple[str, str], dict]" = {}
-_GENERATION = 0
 
 
 def lookup(platform_name: str, bucket: str,
@@ -106,12 +105,4 @@ def lookup(platform_name: str, bucket: str,
 
 
 def clear_memo() -> None:
-    global _GENERATION
     _MEMO.clear()
-    _GENERATION += 1
-
-
-def generation() -> int:
-    """Bumped whenever the memoized caches are dropped, so a lookup may
-    answer differently than before (``repro.backend.routing_key``)."""
-    return _GENERATION

@@ -39,11 +39,6 @@ class TunedConfig:
     fused: bool = True
     k_stream: bool = True
     overlap: Optional[str] = None       # schedules only; None: caller's
-    #: executing ``cute_matmul`` route ("xla" | "pallas" | "auto") this
-    #: variant pins for the shape class; None: the zoo-wide default.
-    #: Not searched by the autotuner (wall-clock under interpret mode is
-    #: not the machine being modelled) — hand-pinnable in a cache file.
-    route: Optional[str] = None
 
     def backend_kwargs(self, unit, platform=None) -> dict:
         """Backend-constructor kwargs this variant implies.  ``unit`` is

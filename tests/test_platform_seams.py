@@ -45,10 +45,10 @@ class TestInterpretDefault:
         from repro.kernels.moe.ops import grouped_matmul
         from repro.kernels.quant.ops import quantize_rowwise
         from repro.kernels.rglru.ops import rglru_scan
-        from repro.kernels.rwkv6.ops import rwkv6_scan
+        from repro.kernels.rwkv6.ops import rwkv6_decode_step
         for fn in (cute_matmul, flash_attention, fused_matmul,
                    grouped_matmul, quantize_rowwise, rglru_scan,
-                   rwkv6_scan):
+                   rwkv6_decode_step):
             sig = inspect.signature(getattr(fn, "__wrapped__", fn))
             assert sig.parameters["interpret"].default is None, fn
 

@@ -1,5 +1,9 @@
 """Serving engine: greedy generation consistency + batching façade."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,6 +98,34 @@ def test_generate_recompiles_when_matmul_route_changes(model):
     again = lower_generate(cfg, params, prompt, max_new_tokens=2).as_text()
     assert pallas != xla
     assert again == xla
+
+
+def test_device_path_imports_no_simulator():
+    """Serving both chip families imports nothing of the paper
+    reproduction: the backend registry, the simulator or the tuner."""
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    prog = """
+import sys
+import jax
+import repro.models.rwkv6, repro.models.transformer
+from repro.configs.registry import concrete_batch, get_config
+from repro.models.base import family_module
+from repro.serving.engine import generate
+for name in ("yi-6b", "rwkv6-7b"):
+    cfg = get_config(name, reduced=True)
+    params = family_module(cfg).init(cfg, jax.random.PRNGKey(0))
+    generate(cfg, params, concrete_batch(cfg, 2, 8, "prefill"),
+             max_new_tokens=2).tokens.block_until_ready()
+print(sorted(m for m in sys.modules
+             if m.split(".")[:2] in (["repro", "backend"], ["repro", "sim"],
+                                     ["repro", "tune"])))
+"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", prog], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert out.stdout.strip().splitlines()[-1] == "[]"
 
 
 def test_serving_engine_batches_requests(model):

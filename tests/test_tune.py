@@ -221,10 +221,34 @@ class TestDispatch:
             backend.set_dispatch_platform(prev)
 
     def test_matmul_route_untouched_without_pin(self):
-        # no committed cache pins a route, so the shape-aware resolution
-        # falls through to the zoo default.
-        assert backend.matmul_backend_string(shape=(4, 4096, 4096)) == "xla"
-        assert backend.matmul_backend_string() == "xla"
+        """The tuned layer prices simulated schedules only: none of its
+        switches changes the device program, whose projections (here
+        RWKV-6's) take ``core.fusion``'s route."""
+        from repro.configs.registry import concrete_batch, get_config
+        from repro.models.base import family_module
+        from repro.serving.engine import lower_generate
+        cfg = get_config("rwkv6-7b", reduced=True)
+        params = jax.eval_shape(
+            lambda k: family_module(cfg).init(cfg, k), jax.random.PRNGKey(0))
+        batch = concrete_batch(cfg, 2, 8, "prefill")
+
+        def text():
+            return lower_generate(cfg, params, batch,
+                                  max_new_tokens=2).as_text()
+
+        base = text()
+        prev = backend.set_dispatch_platform("kunminghu")
+        try:
+            assert text() == base
+        finally:
+            backend.set_dispatch_platform(prev)
+        prev = backend.set_tuned_dispatch(False)
+        try:
+            assert text() == base
+        finally:
+            backend.set_tuned_dispatch(prev)
+        tune.cache.clear_memo()
+        assert text() == base
 
 
 class TestDecodeRegime:

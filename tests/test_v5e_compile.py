@@ -30,7 +30,7 @@ from repro.kernels.matmul.ops import fused_matmul
 from repro.kernels.moe.ops import grouped_matmul
 from repro.kernels.quant.ops import quantize_rowwise
 from repro.kernels.rglru.ops import rglru_scan
-from repro.kernels.rwkv6.ops import rwkv6_decode_step, rwkv6_scan
+from repro.kernels.rwkv6.ops import rwkv6_decode_step
 
 BF, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
 TOKENS = 2048                      # 4 prompts of 512 tokens
@@ -124,10 +124,6 @@ CASES = {
     "rglru_scan_2560": (
         lambda log_a, x: rglru_scan(log_a, x, interpret=False),
         [((1, 2048, 2560), F32), ((1, 2048, 2560), F32)]),
-    "rwkv6_scan_64x64": (
-        lambda r, k, v, lw, u: rwkv6_scan(r, k, v, lw, u, chunk=32,
-                                          interpret=False),
-        [((1, 64, 2048, 64), BF)] * 4 + [((64, 64), F32)]),
     # rwkv6-16l.decode's state: 16 layers, batch 128, 64 heads of 64;
     # and a batch that 8 does not divide (a serving chunk's leftover).
     **{f"rwkv6_decode_step_16x{b}x64x4096": (
