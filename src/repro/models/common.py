@@ -347,6 +347,17 @@ def cache_update(k_cache, v_cache, k_new, v_new, pos):
     return k_cache, v_cache
 
 
+@jax.named_scope("cache_update")
+def cache_write_row(k_stack, v_stack, k_new, v_new, layer, pos):
+    """Write one token's (B, Hkv, 1, D) into layer ``layer`` of the
+    stacked (L, B, Hkv, S, D) caches at position ``pos``: one
+    dynamic-update-slice per stack, which the compiler does in place."""
+    def put(stack, new):
+        return jax.lax.dynamic_update_slice(
+            stack, new.astype(stack.dtype)[None], (layer, 0, 0, pos, 0))
+    return put(k_stack, k_new), put(v_stack, v_new)
+
+
 def remat_policy(cfg: ArchConfig):
     if cfg.remat == "none":
         return None

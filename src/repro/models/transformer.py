@@ -58,26 +58,33 @@ def _norm(cfg, x, w):
 
 def block_apply(cfg: ArchConfig, p, x, *, positions, window: int,
                 kv_cache=None, cache_pos=None):
-    """x: (B, S, d).  Returns (x, new_kv) — new_kv None outside decode."""
+    """x: (B, S, d).  Returns (x, new_kv) — new_kv None without a cache.
+
+    A prompt (S > 1) takes this layer's ``(k_cache, v_cache)`` and writes
+    its rows at ``cache_pos``.  One decode token (S == 1) takes
+    ``((k_stack, v_stack), i)``: every layer's stacked caches and this
+    layer's index.  It writes its row into layer i in place and attends
+    to layer i where it lies in the stack."""
     h = _norm(cfg, x, p["ln_attn"])
     q, k, v = cm.qkv_project(cfg, p["attn"], h, positions)
 
     new_kv = None
-    if kv_cache is not None:
-        k_cache, v_cache = kv_cache
-        k_cache, v_cache = cm.cache_update(k_cache, v_cache, k, v, cache_pos)
-        new_kv = (k_cache, v_cache)
-        if q.shape[2] == 1:                      # decode: one new token
-            from repro.kernels.attention.ops import decode_attention
-            with jax.named_scope("attention"):
-                ctx = decode_attention(
-                    q, k_cache, v_cache, cache_pos + 1,
-                    sm_scale=cfg.sm_scale, window=window,
-                    softcap=cfg.attn_softcap)
-        else:                                    # prefill writes + attends
-            ctx = cm.prefill_attention(cfg, q, k, v, window=window)
-    else:
+    if kv_cache is None:
         ctx = cm.attention(cfg, q, k, v, causal=True, window=window)
+    elif q.shape[2] == 1:                        # decode: one new token
+        from repro.kernels.attention.ops import decode_attention
+        (k_stack, v_stack), i = kv_cache
+        new_kv = cm.cache_write_row(k_stack, v_stack, k, v, i, cache_pos)
+        with jax.named_scope("attention"):
+            k_cache, v_cache = (jax.lax.dynamic_index_in_dim(
+                c, i, keepdims=False) for c in new_kv)
+            ctx = decode_attention(
+                q, k_cache, v_cache, cache_pos + 1,
+                sm_scale=cfg.sm_scale, window=window,
+                softcap=cfg.attn_softcap)
+    else:                                        # prefill writes + attends
+        new_kv = cm.cache_update(*kv_cache, k, v, cache_pos)
+        ctx = cm.prefill_attention(cfg, q, k, v, window=window)
 
     attn_out = cm.attn_out(cfg, p["attn"], ctx)
     if cfg.sandwich_norms:
@@ -134,30 +141,49 @@ def init(cfg: ArchConfig, key):
 def _scan_blocks(cfg: ArchConfig, params, x, *, positions, caches=None,
                  cache_pos=None):
     """One scan over layer *groups*; each step applies the whole group in
-    order (so gemma2's (local, global) pairs stay interleaved).  KV caches
-    are threaded through the scan as per-group ys."""
+    order (so gemma2's (local, global) pairs stay interleaved).  A
+    prompt's KV caches are threaded through the scan as per-group xs and
+    ys.  One decode token's stacked caches ride in the carry with the
+    layer index instead, and each layer writes its row into them in
+    place: no step copies a whole cache."""
     wins = _windows(cfg)
-    policy = cm.remat_policy(cfg)
 
-    def body(carry, layer):
-        x = carry
-        lps, kvs = layer if caches is not None else (layer, None)
-        new_kvs = [] if caches is not None else None
-        for i, window in enumerate(wins):
-            kv = kvs[i] if kvs is not None else None
-            x, new_kv = block_apply(cfg, lps[i], x, positions=positions,
+    def blocks(x, lps, kvs):
+        new_kvs = []
+        for lp, kv, window in zip(lps, kvs, wins):
+            x, new_kv = block_apply(cfg, lp, x, positions=positions,
                                     window=window, kv_cache=kv,
                                     cache_pos=cache_pos)
-            if new_kvs is not None:
-                new_kvs.append(new_kv)
-        return x, (tuple(new_kvs) if new_kvs is not None else None)
+            new_kvs.append(new_kv)
+        return x, tuple(new_kvs)
+
+    in_place = caches is not None and x.shape[1] == 1
+    if in_place:
+        from repro.obs import default_registry
+        default_registry().counter("kv_cache_inplace_steps_total").inc()
+
+        def body(carry, lps):
+            x, stacks, i = carry
+            x, stacks = blocks(x, lps, [(kv, i) for kv in stacks])
+            return (x, stacks, i + 1), None
+
+        init, xs = (x, caches, jnp.int32(0)), params["layers"]
+    elif caches is not None:
+        def body(x, layer):
+            return blocks(x, *layer)
+
+        init, xs = x, (params["layers"], caches)
+    else:
+        def body(x, lps):
+            return blocks(x, lps, (None,) * len(wins))[0], None
+
+        init, xs = x, params["layers"]
 
     if cfg.remat != "none":
-        body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-
-    xs = (params["layers"], caches) if caches is not None else params["layers"]
-    x, ys = jax.lax.scan(body, x, xs)
-    return x, ys
+        body = jax.checkpoint(body, policy=cm.remat_policy(cfg),
+                              prevent_cse=False)
+    out, ys = jax.lax.scan(body, init, xs)
+    return out[:2] if in_place else (out, ys)
 
 
 # ---------------------------------------------------------------------------

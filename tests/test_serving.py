@@ -285,6 +285,30 @@ def test_every_rwkv_decode_step_takes_the_in_place_kernel():
             reg.disable()
 
 
+def test_every_transformer_decode_step_writes_the_cache_in_place():
+    """A traced serving call counts its decode step once as an in-place
+    cache write; its prompt, which writes whole slices, counts nothing."""
+    from repro.obs import default_registry
+    cfg = _cfg()
+    params = jax.eval_shape(lambda k: family_module(cfg).init(cfg, k),
+                            jax.random.PRNGKey(0))
+    reg = default_registry()
+    was = reg.enabled
+    reg.enable()
+    try:
+        reg.clear()
+        jax.clear_caches()
+        lower_generate(cfg, params,
+                       {"tokens": jax.ShapeDtypeStruct((2, 8), jnp.int32)},
+                       max_new_tokens=4, cache_len=12)
+        c = reg.snapshot()["counters"]
+        assert [x["value"] for x in c["kv_cache_inplace_steps_total"]] == [1]
+    finally:
+        reg.clear()
+        if not was:
+            reg.disable()
+
+
 def _serve(argv, monkeypatch):
     from repro.launch import serve
     monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)

@@ -190,3 +190,38 @@ def test_rwkv6_prefill_slice_and_decode_step_compile_for_v5e(one_chip,
             assert "tpu_custom_call" in text
             assert not STATE_SIZED.findall(text)
     jax.clear_caches()
+
+
+#: a copy of yi-6b's stacked K or V cache (32 layers, batch 8, 4 KV
+#: heads, 1280 positions of 128), in any layout
+YI_STACK_COPY = re.compile(r"= bf16\[32,8,4,1280,128\]\S* (copy|copy-start)\(")
+
+
+def test_yi6b_generate_decodes_without_copying_the_kv_cache(one_chip,
+                                                            monkeypatch):
+    """yi-6b at published widths, batch 8, prompt 1024 and 256 new tokens
+    (a 1280-position cache): the compiled ``generate`` program, prefill
+    through the flash kernel as on the chip, writes each decode step's
+    K/V rows into the stacked caches in place.  No copy of a whole
+    stacked cache is left in it."""
+    import repro.kernels
+    import repro.kernels.attention.ops as attn_ops
+    from repro.configs.registry import get_config
+    from repro.models import transformer
+    from repro.serving.engine import lower_generate
+    for mod in (repro.kernels, attn_ops):
+        monkeypatch.setattr(mod, "resolve_interpret", lambda _=None: False)
+    jax.clear_caches()
+    cfg = get_config("yi-6b")
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: transformer.init(cfg, k),
+                       jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one_chip)
+    text = lower_generate(cfg, params, {"tokens": tokens},
+                          max_new_tokens=256, cache_len=1280) \
+        .compile().as_text()
+    jax.clear_caches()
+    assert "tpu_custom_call" in text
+    assert "bf16[32,8,4,1280,128]" in text
+    assert not YI_STACK_COPY.findall(text)
